@@ -4,8 +4,8 @@ Produces the wall-clock evidence the paper's theoretical cost tables lack:
 
   * a short profiled Trainer smoke run per recipe (paper_fp4 vs bf16) —
     the trainer's ``StepTimer`` supplies warmup-excluded p50/p95/p99 step
-    times, tokens/sec and MFU (``step/train_step_*`` entries, percentile
-    fields in the record);
+    times, tokens/sec and, on a TPU, MFU (``step/train_step_*`` entries,
+    percentile fields in the record);
   * a per-phase breakdown (``step/phase_*``).  Phases inside ONE jitted
     step cannot be separately host-timed, so the breakdown uses jitted-
     callable deltas at the same shape: fwd = t(loss); bwd = t(grad) - fwd;
@@ -120,19 +120,21 @@ def run(steps: int = 12) -> None:
     for recipe in ("paper_fp4", "bf16"):
         summ = _smoke_run(model, recipe, steps)
         p50_us = summ.get("p50_ms", float("nan")) * 1e3
+        # MFU exists on a TPU only (the trainer leaves it out elsewhere)
+        has_mfu = "mfu" in summ
         emit(f"step/train_step_{'fp4' if recipe != 'bf16' else 'bf16'}",
              p50_us,
              f"recipe={recipe};steps={int(summ['steps'])};"
              f"warmup={int(summ['warmup'])};"
              f"spikes={int(summ.get('spikes', 0))};"
-             f"mfu={summ.get('mfu', float('nan')):.5f};"
-             f"writer_dropped={int(summ['writer_dropped'])}",
+             + (f"mfu={summ['mfu']:.5f};" if has_mfu else "")
+             + f"writer_dropped={int(summ['writer_dropped'])}",
              extra={"p50_us": summ.get("p50_ms", float("nan")) * 1e3,
                     "p95_us": summ.get("p95_ms", float("nan")) * 1e3,
                     "p99_us": summ.get("p99_ms", float("nan")) * 1e3,
                     "tokens_per_sec": summ.get("tokens_per_sec",
                                                float("nan")),
-                    "mfu": summ.get("mfu", float("nan"))})
+                    **({"mfu": summ["mfu"]} if has_mfu else {})})
     _phase_breakdown(model)
 
 

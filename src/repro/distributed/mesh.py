@@ -12,22 +12,13 @@ __all__ = ["make_mesh"]
 def _resolve_axis_types(axis_types: Sequence[Union[str, object]],
                         n_axes: int):
     """Map 'auto'/'explicit'/'manual' strings (or AxisType values) to
-    ``jax.sharding.AxisType``; returns None when this jax predates
-    AxisType (every axis is implicitly Auto there, so requesting 'auto'
-    degrades gracefully instead of failing)."""
-    AxisType = getattr(jax.sharding, "AxisType", None)
+    ``jax.sharding.AxisType``."""
+    AxisType = jax.sharding.AxisType
     if len(axis_types) != n_axes:
         raise ValueError(f"axis_types has {len(axis_types)} entries for "
                          f"{n_axes} mesh axes")
-    if AxisType is None:
-        if any(str(t).lower().split(".")[-1] != "auto" for t in axis_types):
-            raise ValueError(
-                f"axis_types {axis_types!r} need jax.sharding.AxisType, "
-                "which this jax version does not provide (only 'auto' is "
-                "representable as the implicit default)")
-        return None
     by_name = {"auto": AxisType.Auto, "explicit": AxisType.Explicit,
-               "manual": getattr(AxisType, "Manual", AxisType.Auto)}
+               "manual": AxisType.Manual}
     out = []
     for t in axis_types:
         if isinstance(t, AxisType):
@@ -50,7 +41,7 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
     The single mesh constructor (``launch.mesh.make_production_mesh``
     routes through here).  ``axis_types`` optionally names each axis's
     GSPMD mode ('auto' | 'explicit' | 'manual', or ``jax.sharding.AxisType``
-    values); omitted or 'auto' works on every supported jax version.
+    values); omitted means JAX's default.
     """
     n = int(np.prod(shape))
     devices = list(devices if devices is not None else jax.devices())[:n]
@@ -58,7 +49,6 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
         raise ValueError(f"need {n} devices, have {len(devices)}")
     arr = np.asarray(devices).reshape(shape)
     if axis_types is not None:
-        resolved = _resolve_axis_types(axis_types, len(axes))
-        if resolved is not None:
-            return jax.sharding.Mesh(arr, axes, axis_types=resolved)
+        return jax.sharding.Mesh(
+            arr, axes, axis_types=_resolve_axis_types(axis_types, len(axes)))
     return jax.sharding.Mesh(arr, axes)

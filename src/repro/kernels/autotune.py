@@ -186,10 +186,10 @@ def autotune_qmm(m: int, n: int, k: int, *,
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.fp4_matmul import fused_qmm
+    from repro.kernels.fp4_matmul import fused_qmm, interpret_mode
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     dt = jnp.dtype(dtype)
     ka, kb = jax.random.split(jax.random.PRNGKey(0))
     a_shape = (k, m) if trans_a else (m, k)

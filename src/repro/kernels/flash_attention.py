@@ -23,8 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fp4_matmul import compiler_params
-
 __all__ = ["flash_attention_fwd"]
 
 NEG_INF = -1e30
@@ -93,7 +91,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

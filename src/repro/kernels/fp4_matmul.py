@@ -95,7 +95,7 @@ from repro.core.formats import FORMATS
 from repro.kernels.rounding import (group_scale, hash_uniform, round_to_grid,
                                     snap_to_dtype, uniform_from_bits)
 
-__all__ = ["fp4_matmul", "fused_qmm", "quantize_panels", "compiler_params",
+__all__ = ["fp4_matmul", "fused_qmm", "quantize_panels", "interpret_mode",
            "finalize_quant_stats", "QUANT_MODES", "STATS_WIDTH",
            "PIPELINES", "default_pipeline", "use_pipeline",
            "stream_supported", "resolve_pipeline"]
@@ -125,15 +125,34 @@ _BQ_CACHE_BYTES = 4 * 1024 * 1024
 STATS_WIDTH = 8
 _STATS_BIG = 3.0e38
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; the repo
-# must run on both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: everywhere but a TPU.
+
+    The one place every kernel entry point decides it when the caller does
+    not; a test that compiles for a described TPU patches this function.
+    """
+    return jax.default_backend() != "tpu"
 
 
-def compiler_params(**kw):
-    """Version-portable ``pltpu.CompilerParams`` constructor."""
-    return _CompilerParams(**kw)
+def _mxu_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """In-kernel dot with an f32 accumulator.
+
+    bf16 products are exact in f32, so bf16 operands pin DEFAULT
+    precision: Mosaic refuses the fp32 contract precision that a global
+    ``jax_default_matmul_precision="highest"`` would otherwise request.
+    """
+    prec = jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=prec)
+
+
+def _amax_f32(mag: jnp.ndarray) -> jnp.ndarray:
+    """Whole-group amax as an f32 scalar (``tile`` and ``tensor`` groups).
+
+    Mosaic cannot reduce a bf16 array to a scalar; the max of the exact
+    f32 upcast is the same value, so this changes no bit anywhere.
+    """
+    return jnp.max(mag.astype(jnp.float32))
 
 
 def _pick_tile(dim: int, block: int = 128) -> int:
@@ -358,7 +377,7 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
         @pl.when(s == 0)
         def _():
             amax_ref[...] = jnp.maximum(amax_ref[...],
-                                        jnp.max(mag).astype(jnp.float32))
+                                        _amax_f32(mag))
 
     # --- quantize sweep ---------------------------------------------------
     def _quantize():
@@ -391,7 +410,7 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
                     cs = slice(j * block, (j + 1) * block)
                     sub, smag = xt[rs, cs], mag[rs, cs]
                     amax = (jnp.max(smag, axis=1, keepdims=True) if per_row
-                            else jnp.max(smag))
+                            else _amax_f32(smag))
                     scale = group_scale(amax, fmt, pow2, qm)
                     sc = scale.astype(in_dt)
                     nsub = noise[rs, cs] if noise is not None else None
@@ -415,7 +434,11 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
                         _stats_slab_flush(sacc_ref, p * (bq // block) + i,
                                           lane, st)
         else:  # token / tensor: scale broadcast from the amax scratch
-            scale = group_scale(amax_ref[...], fmt, pow2, qm)
+            # tensor: one scalar scale (Mosaic cannot broadcast a (1, 1)
+            # vector over sublanes and lanes at once).
+            amax = (amax_ref[...] if grid_kind == "token"
+                    else amax_ref[0, 0])
+            scale = group_scale(amax, fmt, pow2, qm)
             sc = scale.astype(in_dt)
             qt = round_to_grid(xt / sc, fmt, noise) * sc
             o_ref[...] = qt.T if emit_trans else qt
@@ -529,7 +552,7 @@ def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
         scratch_shapes=scratch,
         # Scratch accumulators (amax sweeps, stats epilogue) need sequential
         # revisiting; the quantize pass is VPU/bandwidth-bound anyway.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
     )(*operands)
@@ -559,7 +582,7 @@ def quantize_panels(t: jnp.ndarray, *, mode: str = "block",
     """
     assert mode in QUANT_MODES and mode != "pass", mode
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     m_eff, k_eff = (t.shape[1], t.shape[0]) if trans else t.shape
     m_real, k_real = real_dims if real_dims is not None else (m_eff, k_eff)
     q, stats = _quantize_operand(
@@ -585,7 +608,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k, trans_a, trans_b):
     bt = b_ref[...]
     if trans_b:
         bt = bt.T
-    acc_ref[...] += jnp.dot(at, bt, preferred_element_type=jnp.float32)
+    acc_ref[...] += _mxu_dot(at, bt)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _():
@@ -621,7 +644,7 @@ def _tiled_matmul(a: jnp.ndarray, b: jnp.ndarray, *, trans_a: bool,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -663,7 +686,7 @@ def _qdq_stream_tile(xt, *, mode, fmt, pow2, qm, block, noise, sacc_ref,
             cs = slice(j * block, (j + 1) * block)
             sub, smag = xt[rs, cs], mag[rs, cs]
             amax = (jnp.max(smag, axis=1, keepdims=True) if per_row
-                    else jnp.max(smag))
+                    else _amax_f32(smag))
             scale = group_scale(amax, fmt, pow2, qm)
             sc = scale.astype(in_dt)
             nsub = noise[rs, cs] if noise is not None else None
@@ -819,7 +842,7 @@ def _stream_kernel(*refs, a_mode, b_mode, fmt_a, fmt_b, a_pow2, b_pow2,
         # Requantized per i-revisit; stats must still fold exactly once.
         bt = _qdq_b(gate=(i == 0))
 
-    acc_ref[...] += jnp.dot(at, bt, preferred_element_type=jnp.float32)
+    acc_ref[...] += _mxu_dot(at, bt)
 
     @pl.when(kk == nk - 1)
     def _():
@@ -919,7 +942,7 @@ def _stream_qmm(a: jnp.ndarray, b: jnp.ndarray, *, a_mode, b_mode,
         scratch_shapes=scratch,
         # Scratch (acc, LHS panel cache, stats) is revisited across grid
         # steps -> sequential order required.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
     )(*operands)

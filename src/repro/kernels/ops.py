@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels: padding, GQA handling,
 custom_vjp glue, and interpret-mode fallback for CPU.
 
-On CPU (this container) every entry point runs with ``interpret=True`` —
-the kernel body executes in Python, validating the exact TPU code path.
+Off a TPU every entry point runs with ``interpret=True`` (decided by
+``fp4_matmul.interpret_mode``) — the kernel body executes in Python,
+validating the exact TPU code path.
 """
 from __future__ import annotations
 
@@ -24,10 +25,6 @@ __all__ = ["fp4_matmul", "pallas_qmm", "quantize_blockwise",
            "flash_attention"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pad2d(x, block):
     m, n = x.shape
     pm, pn = (-m) % block, (-n) % block
@@ -46,7 +43,7 @@ def fp4_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
     nothing and per-row amax over the padded segment is unchanged for the
     rows that exist); padding M/N rows/cols are sliced away.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _mm.interpret_mode() if interpret is None else interpret
     xp, m, k = _pad2d(x, block)
     wp, _, n = _pad2d(w, block)
     y = _mm.fp4_matmul(xp, wp, x_fmt=x_fmt, w_fmt=w_fmt, block=block,
@@ -86,7 +83,7 @@ def pallas_qmm(a: jnp.ndarray, b: jnp.ndarray,
     returns ``(y, (stats_a, stats_b))`` raw telemetry-epilogue vectors
     (``kernels.fp4_matmul.finalize_quant_stats`` reduces them).
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _mm.interpret_mode() if interpret is None else interpret
     ap, _, _ = _pad2d(a, block)
     bp, _, _ = _pad2d(b, block)
     m = a.shape[1] if trans_a else a.shape[0]
@@ -126,7 +123,7 @@ def pallas_qmm(a: jnp.ndarray, b: jnp.ndarray,
 def quantize_blockwise(x: jnp.ndarray, fmt_name: str = "fp4_e2m1",
                        block: int = 128, *, per_row: bool = False,
                        interpret: Optional[bool] = None) -> jnp.ndarray:
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _mm.interpret_mode() if interpret is None else interpret
     xp, m, n = _pad2d(x, block)
     y = _q.quantize_blockwise(xp, fmt_name, block, per_row=per_row,
                               interpret=interpret)
@@ -178,5 +175,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Differentiable flash attention: Pallas forward (TPU target),
     chunked-jnp backward.  q/k/v: (B, S, H|KVH, D)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _mm.interpret_mode() if interpret is None else interpret
     return _flash(q, k, v, causal, chunk, interpret)

@@ -172,8 +172,12 @@ def hash_bits(shape, seed: jnp.ndarray, row0, col0) -> jnp.ndarray:
 
 
 def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    """Map uint32 bits to f32 uniform [0, 1) using the top 24 bits."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(2 ** -24)
+    """Map uint32 bits to f32 uniform [0, 1) using the top 24 bits.
+
+    The 24-bit integer goes to f32 through int32 (a same-width bitcast,
+    then an exact signed convert): Mosaic has no uint32 -> f32 cast."""
+    top = jax.lax.bitcast_convert_type(bits >> np.uint32(8), jnp.int32)
+    return top.astype(jnp.float32) * np.float32(2 ** -24)
 
 
 def hash_uniform(shape, seed: jnp.ndarray, row0, col0) -> jnp.ndarray:

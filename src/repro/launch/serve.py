@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core.recipe import RECIPES
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.train.serving_runtime import (ContinuousBatcher,
                                          quantize_weights_for_serving)
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--weight-quant", default="none",
                     help="none | fp8_e4m3 | fp4_e2m1 (weight-only serving)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.reduced:
         import importlib

@@ -9,9 +9,9 @@ index-addressed data pipeline and GSPMD sharding need no other coordination.
 """
 import argparse
 
-
 from repro.configs.base import TrainConfig, get_config
 from repro.data import make_pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.train.trainer import Trainer
 
@@ -46,6 +46,7 @@ def main() -> None:
                          "theory factors)")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.reduced:
         import importlib

@@ -23,9 +23,10 @@ Three pieces:
   * **Flops/MFU helpers** — :func:`train_step_flops` turns
     ``core.cost_model.ModelDims`` into a per-step training-flop count
     (fwd + dgrad + wgrad = 3x forward matmul flops);
-    :func:`device_peak_flops` provides the peak-flops denominator (known
-    TPU generations, ``REPRO_PEAK_FLOPS`` env override, a nominal CPU
-    figure so smoke runs still produce a number).
+    :func:`device_peak_flops` provides the per-chip peak-flops
+    denominator (known TPU generations or the ``REPRO_PEAK_FLOPS`` env
+    override; any other device raises).  MFU is a device metric: callers
+    on the CPU leave it out.
 
 Capturing a real trace around the annotated regions:
 
@@ -60,11 +61,7 @@ def phase_span(name: str):
     dispatch+sync of one step, controller/writer work) so it shows as a
     named slice in a ``jax.profiler`` trace.  No-op overhead when no trace
     is being captured (~sub-microsecond), so it is always on."""
-    ann = getattr(jax.profiler, "TraceAnnotation", None)
-    if ann is None:  # very old jax: annotation API absent
-        yield
-        return
-    with ann(name):
+    with jax.profiler.TraceAnnotation(name):
         yield
 
 
@@ -162,9 +159,11 @@ class StepTimer:
         """Step-time stats: ``steps`` (post-warmup count), ``warmup``,
         ``spikes`` (excluded recompile-spike count, plus ``spike_max_ms``
         when any), ``mean_ms``/``p50_ms``/``p95_ms``/``p99_ms``, and — when
-        the caller supplies the model numbers — ``tokens_per_sec`` and
-        ``mfu``, both computed at the p50 step time (median: robust to
-        straggler steps).
+        the caller supplies the model numbers — ``tokens_per_sec``,
+        ``flops_per_sec`` and ``mfu``, all at the p50 step time (median:
+        robust to straggler steps).  ``peak_flops`` is the peak of every
+        chip the step ran on together (chips x per-chip peak); without it
+        there is no ``mfu``.
         """
         ts = self.times
         out: Dict[str, float] = {"steps": len(ts), "warmup": self.warmup,
@@ -182,9 +181,8 @@ class StepTimer:
             out["tokens_per_sec"] = tokens_per_step / p50
         if flops_per_step is not None and p50 > 0:
             out["flops_per_sec"] = flops_per_step / p50
-            if peak_flops is None:
-                peak_flops = device_peak_flops()
-            out["mfu"] = flops_per_step / p50 / peak_flops
+            if peak_flops is not None:
+                out["mfu"] = flops_per_step / p50 / peak_flops
         return out
 
 
@@ -203,10 +201,9 @@ def train_step_flops(dims, tokens_per_step: float) -> float:
     return 3.0 * dims.total_fwd_flops * tokens_per_step
 
 
-# Peak dense matmul throughput (flops/sec, bf16) by TPU device kind, for
-# the MFU denominator.  The CPU fallback is a nominal figure (one AVX-512
-# core's ~100 GF/s) — CPU "MFU" is only meaningful as a run-to-run trend,
-# which is exactly how BENCH_step.json uses it.
+# Peak dense matmul throughput (flops/sec, bf16) of one chip by TPU device
+# kind, for the MFU denominator (Google Cloud TPU documentation, per
+# generation).
 _PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -214,24 +211,24 @@ _PEAK_FLOPS = {
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
-    "cpu": 1e11,
 }
 
 
 def device_peak_flops(device=None) -> float:
-    """Peak flops/sec of ``device`` (default: the first local device).
+    """Peak flops/sec of one chip of ``device``'s kind (default: the
+    first local device).
 
     Resolution order: ``REPRO_PEAK_FLOPS`` env var (authoritative — set it
-    when your part's spec is known), the known-TPU table, the CPU nominal
-    figure.  Unknown accelerators fall back to the CPU figure rather than
-    raising: MFU should degrade to "trend-only", never crash a report.
+    when your part's spec is known), then the known-TPU table.  Any other
+    device raises: a peak it does not have would make MFU a made-up number.
     """
     env = os.environ.get("REPRO_PEAK_FLOPS")
     if env:
         return float(env)
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu")
+    kind = device.device_kind
     for name, peak in _PEAK_FLOPS.items():
-        if name != "cpu" and kind.lower().startswith(name.lower()):
+        if kind.lower().startswith(name.lower()):
             return peak
-    return _PEAK_FLOPS["cpu"]
+    raise ValueError(f"no peak flops known for device kind {kind!r} "
+                     f"(platform {device.platform}); set REPRO_PEAK_FLOPS")

@@ -26,7 +26,7 @@ Responsibilities:
     steps are emitted as ``{"event": "straggler", ...}`` JSONL events;
   * measured-performance observability: every step is timed with device
     sync into a ``telemetry.profiler.StepTimer`` (``step_time_summary()``
-    reports p50/p95/p99, tokens/sec, MFU), the loop's data/step/host
+    reports p50/p95/p99, tokens/sec, and MFU on a TPU), the loop's data/step/host
     regions carry ``jax.profiler`` phase spans, and the JSONL stream goes
     through the host-offloaded ``AsyncJsonlWriter`` (bounded queue +
     writer thread) so logging never blocks the step;
@@ -60,7 +60,8 @@ from repro.distributed.sharding import ShardingRules, default_rules
 from repro.models.model import Model
 from repro.optim import init_compression_state
 from repro.telemetry.controller import PrecisionController
-from repro.telemetry.profiler import StepTimer, phase_span, train_step_flops
+from repro.telemetry.profiler import (StepTimer, device_peak_flops,
+                                      phase_span, train_step_flops)
 from repro.telemetry.writer import AsyncJsonlWriter
 from repro.train.train_step import (make_optimizer, make_train_step,
                                     train_step_shardings)
@@ -194,10 +195,12 @@ class Trainer:
             # not pay a resharding transfer (and so donation stays legal).
             p_sh, o_sh, c_sh, _, _, _ = train_step_shardings(
                 self.model, self.tcfg, self.rules)[0]
+            # comp_state too, even as the replicated scalar placeholder:
+            # left uncommitted, its replicated step output would not match
+            # it and the second step would compile again.
             params = jax.device_put(params, p_sh)
             opt_state = jax.device_put(opt_state, o_sh)
-            if use_fp8:
-                comp_state = jax.device_put(comp_state, c_sh)
+            comp_state = jax.device_put(comp_state, c_sh)
         return TrainState(params, opt_state, comp_state, 0)
 
     def _step_fn(self, plan: PrecisionPlan,
@@ -337,12 +340,19 @@ class Trainer:
 
     def step_time_summary(self) -> Dict[str, float]:
         """Measured step-time statistics for this trainer's run so far:
-        p50/p95/p99/mean (ms), tokens/sec at the median step, and MFU from
-        the model's ``ModelDims`` flops (``telemetry.profiler`` summary)."""
+        p50/p95/p99/mean (ms), tokens/sec at the median step, and — on a
+        TPU only — MFU from the model's ``ModelDims`` flops over every chip
+        of the mesh (``telemetry.profiler`` summary)."""
         tokens = self.tcfg.global_batch * self.tcfg.seq_len
+        devices = (list(self.rules.mesh.devices.flat)
+                   if self.rules is not None else jax.devices()[:1])
+        peak = None
+        if devices[0].platform == "tpu":
+            peak = len(devices) * device_peak_flops(devices[0])
         return self.timer.summary(
             tokens_per_step=tokens,
-            flops_per_step=train_step_flops(self.dims, tokens))
+            flops_per_step=train_step_flops(self.dims, tokens),
+            peak_flops=peak)
 
     # ------------------------------------------------------------------
 

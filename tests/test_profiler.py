@@ -124,7 +124,10 @@ def test_device_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_PEAK_FLOPS", "1.23e14")
     assert device_peak_flops() == 1.23e14
     monkeypatch.delenv("REPRO_PEAK_FLOPS")
-    assert device_peak_flops() > 0  # table/CPU fallback, never raises
+    # the CPU has no entry in the peak table: no made-up denominator
+    assert jax.devices()[0].platform == "cpu"
+    with pytest.raises(ValueError, match="no peak flops"):
+        device_peak_flops()
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,150 @@
+"""Compile the main-path kernels for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with jaxlib; ``get_topology_desc`` describes
+a v5e it can target.  Nothing runs: each test lowers and compiles at
+GPT-2-125M's training widths (8192 tokens, d_model 768, d_ff 3072) and
+asserts that the compiled program still holds the Mosaic kernel
+(``tpu_custom_call``).  This is what interpret-mode tests cannot see:
+the compiler refuses unaligned slices, scoped-VMEM overruns and
+operations Mosaic has no lowering for.
+
+The topology is described inside a module fixture, never at import, so
+every test worker collects the same tests and only the worker that runs
+this file loads the TPU library.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.base import TrainConfig
+from repro.configs.gpt2_125m import CONFIG as GPT2_125M
+from repro.core.recipe import RECIPES, as_plan
+from repro.models import build_model
+from repro.train.train_step import make_optimizer, make_train_step
+
+FM = importlib.import_module("repro.kernels.fp4_matmul")
+FA = importlib.import_module("repro.kernels.flash_attention")
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+TOKENS, D, FF, QKV = 8192, 768, 3072, 3 * 768
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_compile_cache):
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+    return shapes
+
+
+def _bf16(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+
+def _seed():
+    return jax.ShapeDtypeStruct((1,), jnp.int32)
+
+
+# name -> (fused_qmm keywords, operand shapes as stored)
+QMM_CASES = {
+    # the paper's FFN forward: per-block FP4 activations, per-tile weights
+    "stream_fwd_block_tile": (
+        dict(a_mode="block", b_mode="tile"), (_bf16(TOKENS, D),
+                                              _bf16(D, FF))),
+    # attention linears under paper_fp4: per-token FP8 (two-pass)
+    "attn_token_token_fp8": (
+        dict(a_mode="token", b_mode="token", a_fmt="fp8_e4m3",
+             b_fmt="fp8_e4m3"), (_bf16(TOKENS, D), _bf16(D, QKV))),
+    # FFN wgrad: x^T @ g, FP8 per-block along the token axis
+    "wgrad_block_block_trans_a": (
+        dict(a_mode="block", b_mode="block", a_fmt="fp8_e4m3",
+             b_fmt="fp8_e5m2", trans_a=True), (_bf16(TOKENS, D),
+                                               _bf16(TOKENS, FF))),
+    # demoted FP4 wgrad with stochastic rounding (hardware PRNG noise)
+    "wgrad_sr_fp4": (
+        dict(a_mode="block", b_mode="block", trans_a=True, a_sr=True,
+             b_sr=True), (_bf16(TOKENS, D), _bf16(TOKENS, FF), _seed(),
+                          _seed())),
+    # packed-weight decode: FP4 activations, pre-quantized panel passes
+    "decode_block_pass": (
+        dict(a_mode="block", b_mode="pass"), (_bf16(128, D), _bf16(D, FF))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(QMM_CASES))
+def test_fused_qmm_compiles_for_v5e(on_chip, case):
+    kw, shapes = QMM_CASES[case]
+
+    def f(a, b, *seeds):
+        sk = dict(zip(("seed_a", "seed_b"), seeds))
+        return FM.fused_qmm(a, b, interpret=False, **kw, **sk)
+
+    compiled = jax.jit(f).lower(*on_chip(shapes)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_flash_attention_fwd_compiles_for_v5e(on_chip):
+    # (batch 16 x 12 heads, seq 1024, head_dim 64)
+    q = _bf16(16 * 12, 1024, 64)
+    compiled = jax.jit(lambda q, k, v: FA.flash_attention_fwd(
+        q, k, v, bq=128, bk=128, interpret=False)).lower(
+            *on_chip((q, q, q))).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_paper_fp4_train_step_compiles_for_v5e(on_chip, monkeypatch):
+    """One whole fused-kernel paper_fp4 train step at GPT-2-125M widths,
+    two layers deep; it must fit one 16 GB chip."""
+    # Kernels decide interpret mode from the backend, which is the CPU
+    # here: steer them to the chip's compiler.
+    monkeypatch.setattr(FM, "interpret_mode", lambda: False)
+    cfg = GPT2_125M.replace(n_layers=2, linear_impl="pallas")
+    model = build_model(cfg)
+    seq, batch = 1024, TOKENS // 1024
+    tcfg = TrainConfig(recipe="paper_fp4", total_steps=20,
+                       global_batch=batch, seq_len=seq)
+    params = model.abstract_params(jnp.float32)
+    opt = jax.eval_shape(make_optimizer(model, tcfg).init, params)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    args = (params, opt, jax.ShapeDtypeStruct((), jnp.float32),
+            {"tokens": tokens, "targets": tokens},
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+    step = make_train_step(model, tcfg,
+                           as_plan(RECIPES["paper_fp4"], cfg.n_layers),
+                           donate=False)
+    compiled = step.lower(*on_chip(args)).compile()
+    assert KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9

@@ -93,6 +93,20 @@ def test_eval_returns_ppl(tiny_setup):
     assert ev["val_ppl"] == pytest.approx(np.exp(ev["val_loss"]), rel=1e-6)
 
 
+def test_step_time_summary_has_no_mfu_off_tpu(tiny_setup):
+    """MFU is a device metric: on the CPU the summary leaves it out
+    (decided by platform) instead of dividing by a made-up peak."""
+    cfg, model, pipe = tiny_setup
+    tcfg = TrainConfig(recipe="bf16", total_steps=5, global_batch=8,
+                       seq_len=64, log_every=0)
+    tr = Trainer(model, tcfg, pipe)
+    tr.train()
+    summ = tr.step_time_summary()
+    assert jax.devices()[0].platform == "cpu"
+    assert summ["steps"] == 3 and summ["tokens_per_sec"] > 0
+    assert summ["flops_per_sec"] > 0 and "mfu" not in summ
+
+
 def test_straggler_monitor_flags_outliers():
     mon = StepTimeMonitor(factor=2.0, warmup=3)
     flagged = []
