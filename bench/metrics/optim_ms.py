@@ -1,0 +1,16 @@
+"""Device time per step of the ops the step graph scopes ``optim``
+(``train_step.py``: gradient clipping and the optimizer's update),
+averaged over the chips."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+import scopes  # noqa: E402
+
+
+def read(ctx):
+    red = scopes.window(ctx)
+    s = scopes.mean_scope_s(red or {}).get("optim", 0.0)
+    return 1e3 * s / ctx["steps"] if s > 0 else None

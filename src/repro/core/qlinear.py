@@ -50,7 +50,6 @@ from repro.core.packed import PackedTensor
 from repro.core.quantize import BF16_SPEC, QuantSpec, qdq
 from repro.core.recipe import MatmulRecipe
 from repro.telemetry import collect as telemetry
-from repro.telemetry.profiler import graph_span
 
 __all__ = ["qmatmul", "pallas_qmatmul", "pallas_qmatmul_two_pass",
            "pallas_qmatmul_stats", "qlinear", "packed_linear", "dot_qdq",
@@ -103,13 +102,12 @@ def dot_qdq(a: jnp.ndarray, b: jnp.ndarray,
             sr_b=bool(spec_b.stochastic) and key_data is not None,
             cell=cell)
     with _role_scope(role):
-        with graph_span("quantize"):   # phase metadata for attribution
-            aq = qdq(a, spec_a, reduction_axis=1,
-                     stochastic_key=_maybe_key(key_data, spec_a, salt),
-                     axes=axes_a)
-            bq = qdq(b, spec_b, reduction_axis=0,
-                     stochastic_key=_maybe_key(key_data, spec_b, salt + 1),
-                     axes=axes_b)
+        aq = qdq(a, spec_a, reduction_axis=1,
+                 stochastic_key=_maybe_key(key_data, spec_a, salt),
+                 axes=axes_a)
+        bq = qdq(b, spec_b, reduction_axis=0,
+                 stochastic_key=_maybe_key(key_data, spec_b, salt + 1),
+                 axes=axes_b)
         return jax.lax.dot(aq, bq, precision=precision)
 
 

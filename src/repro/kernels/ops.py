@@ -19,7 +19,6 @@ from repro.kernels import fp4_matmul as _mm
 from repro.kernels import quantize as _q
 from repro.kernels import flash_attention as _fa
 from repro.models.attention import chunked_attention
-from repro.telemetry.profiler import graph_span
 
 __all__ = ["fp4_matmul", "pallas_qmm", "quantize_blockwise",
            "flash_attention"]
@@ -104,16 +103,15 @@ def pallas_qmm(a: jnp.ndarray, b: jnp.ndarray,
         from repro.kernels.rounding import fold_seed
         seed_a = fold_seed(key_data, salt, 0) if a_sr else None
         seed_b = fold_seed(key_data, salt, 1) if b_sr else None
-    with graph_span("quantize"):   # fused quantize+matmul: one phase scope
-        out = _mm.fused_qmm(
-            ap, bp, a_mode=mode_a, b_mode=mode_b,
-            a_fmt=spec_a.fmt, b_fmt=spec_b.fmt,
-            a_pow2=spec_a.pow2_scale, b_pow2=spec_b.pow2_scale,
-            a_sr=a_sr, b_sr=b_sr, seed_a=seed_a, seed_b=seed_b,
-            trans_a=trans_a, trans_b=trans_b, block=block,
-            bm=bm, bn=bn, bk=bk, pipeline=pipeline,
-            real_dims=(m, k, n), collect_stats=collect_stats,
-            interpret=interpret)
+    out = _mm.fused_qmm(
+        ap, bp, a_mode=mode_a, b_mode=mode_b,
+        a_fmt=spec_a.fmt, b_fmt=spec_b.fmt,
+        a_pow2=spec_a.pow2_scale, b_pow2=spec_b.pow2_scale,
+        a_sr=a_sr, b_sr=b_sr, seed_a=seed_a, seed_b=seed_b,
+        trans_a=trans_a, trans_b=trans_b, block=block,
+        bm=bm, bn=bn, bk=bk, pipeline=pipeline,
+        real_dims=(m, k, n), collect_stats=collect_stats,
+        interpret=interpret)
     if collect_stats:
         y, stats = out
         return y[:m, :n], stats

@@ -26,6 +26,7 @@ from repro.configs.base import ModelConfig
 from repro.core.recipe import MatmulRecipe
 from repro.nn.layers import linear, rope, shard_hint
 from repro.nn.params import ParamSpec
+from repro.telemetry.profiler import graph_span
 
 __all__ = ["attn_param_specs", "cross_attn_param_specs", "attention",
            "cross_attention", "attn_cache_spec", "init_attn_cache",
@@ -90,6 +91,7 @@ def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     return x.reshape(b, s, kvh * n_rep, d)
 
 
+@graph_span("attn")
 def chunked_attention(
     q: jnp.ndarray,           # (B, Sq, H, D)
     k: jnp.ndarray,           # (B, Sk, KVH, D)
@@ -190,6 +192,7 @@ def chunked_attention(
 # Full attention sublayer (projections + SDPA [+ cache update])
 # ---------------------------------------------------------------------------
 
+@graph_span("attn")
 def attention(
     params: Dict[str, jnp.ndarray],
     cfg: ModelConfig,
@@ -250,6 +253,7 @@ def attention(
                   axes=("tokens", "heads", "embed")), new_cache
 
 
+@graph_span("attn")
 def cross_attention(
     params: Dict[str, jnp.ndarray],
     cfg: ModelConfig,

@@ -10,6 +10,7 @@ from repro.configs.base import ModelConfig
 from repro.core.recipe import MatmulRecipe
 from repro.nn.layers import ACTIVATIONS, linear, shard_hint
 from repro.nn.params import ParamSpec
+from repro.telemetry.profiler import graph_span
 
 __all__ = ["mlp_param_specs", "mlp"]
 
@@ -29,6 +30,7 @@ def mlp_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     }
 
 
+@graph_span("mlp")
 def mlp(params: Dict[str, jnp.ndarray], cfg: ModelConfig, x: jnp.ndarray,
         recipe: MatmulRecipe) -> jnp.ndarray:
     """x: (B, S, D) -> (B, S, D).  All matmuls quantized per ``recipe`` —

@@ -8,6 +8,7 @@ the ``plan`` argument: a layer-resolved ``PrecisionPlan`` (or a
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,8 +23,23 @@ from repro.nn.layers import (apply_norm, linear, shard_hint,
                              sincos_positions)
 from repro.nn.params import ParamSpec, init_params, param_count, spec_shapes
 from repro.telemetry import collect as telemetry
+from repro.telemetry.profiler import graph_span
 
 __all__ = ["Model", "build_model"]
+
+
+_PARAM_SCOPES = {"embed": "embed", "pos_embed": "embed", "head": "head",
+                 "wq": "attn", "wk": "attn", "wv": "attn", "wo": "attn",
+                 "w_gate": "mlp", "w_up": "mlp", "w_down": "mlp"}
+
+
+def _param_scope(keys) -> Optional[str]:
+    """The layer scope of the ops that use the parameter at ``keys`` (its
+    path's dict keys; None for a list index)."""
+    if any(isinstance(k, str) and k.endswith("norm") for k in keys[:-1]):
+        return "norm"
+    return next((_PARAM_SCOPES[k] for k in reversed(keys)
+                 if k in _PARAM_SCOPES), None)
 
 
 def _encoder_cfg(cfg: ModelConfig) -> ModelConfig:
@@ -78,15 +94,21 @@ class Model:
         are expanded to the compute dtype at their consuming matmul."""
         specs = self.param_specs()
 
-        def cast(p, s):
+        def cast(path, p, s):
             if isinstance(p, PackedTensor):
                 return p
             if s.dtype is None and jnp.issubdtype(p.dtype, jnp.floating):
-                return p.astype(self._dt)
+                # the cast is the work of the layer that uses the weight
+                scope = _param_scope([getattr(k, "key", None)
+                                      for k in path])
+                with (graph_span(scope) if scope
+                      else contextlib.nullcontext()):
+                    return p.astype(self._dt)
             return p
 
-        return jax.tree.map(cast, params, specs,
-                            is_leaf=lambda x: isinstance(x, PackedTensor))
+        return jax.tree_util.tree_map_with_path(
+            cast, params, specs,
+            is_leaf=lambda x: isinstance(x, PackedTensor))
 
     def abstract_params(self, dtype=jnp.float32):
         return spec_shapes(self.param_specs(), dtype)
@@ -114,6 +136,7 @@ class Model:
     # Embedding / head
     # ------------------------------------------------------------------
 
+    @graph_span("embed")
     def _embed(self, params, tokens: jnp.ndarray,
                positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         cfg = self.cfg
@@ -127,6 +150,7 @@ class Model:
             x = x + (pe if pos.ndim == tokens.ndim else pe[None])
         return shard_hint(x, ("batch", "seq", "embed"))
 
+    @graph_span("head")
     def _head(self, params, x: jnp.ndarray,
               plan: PrecisionPlan) -> jnp.ndarray:
         cfg = self.cfg
@@ -210,6 +234,7 @@ class Model:
         return params["head"].astype(self._dt)
 
     @staticmethod
+    @graph_span("head")
     def _xent_terms(logits: jnp.ndarray, targets: jnp.ndarray):
         """Returns (sum nll, sum lse^2, n_tokens) over masked positions."""
         mask = (targets >= 0)
@@ -221,6 +246,36 @@ class Model:
         nll = jnp.sum((lse - gold) * mask)
         z2 = jnp.sum((lse * mask) ** 2)
         return nll, z2, mask.sum()
+
+    @graph_span("head")
+    def _chunked_xent(self, params, h: jnp.ndarray, targets: jnp.ndarray,
+                      plan: PrecisionPlan):
+        """``_xent_terms`` of the head's logits, seq-chunked under remat."""
+        cfg = self.cfg
+        w = self._head_weight(self.cast_params(params))
+        c = cfg.loss_chunk
+        s = h.shape[1]
+        assert s % c == 0, (s, c)
+        hc = h.reshape(h.shape[0], s // c, c, -1).transpose(1, 0, 2, 3)
+        tc = targets.reshape(targets.shape[0], s // c, c).transpose(1, 0, 2)
+
+        @jax.checkpoint
+        def chunk_terms(h_c, t_c):
+            # telemetry stays off in here: stats pushed from inside the
+            # chunk scan could not legally escape its trace scope.
+            with telemetry.suppressed():
+                logits = linear(h_c, w, plan.head_linear, cfg,
+                                axes=("tokens", "embed", "vocab"))
+            return self._xent_terms(logits, t_c)
+
+        def body(carry, xs):
+            nll, z2, n = carry
+            d_nll, d_z2, d_n = chunk_terms(*xs)
+            return (nll + d_nll, z2 + d_z2, n + d_n), None
+
+        return jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
+                   jnp.zeros((), jnp.int32)), (hc, tc))[0]
 
     def loss(self, params, batch: Dict[str, jnp.ndarray],
              plan) -> Tuple[jnp.ndarray, Dict]:
@@ -238,32 +293,7 @@ class Model:
             nll, z2, n = self._xent_terms(logits, targets)
         else:
             h, aux = self.hidden(params, batch, plan)
-            w = self._head_weight(self.cast_params(params))
-            c = cfg.loss_chunk
-            s = h.shape[1]
-            assert s % c == 0, (s, c)
-            hc = h.reshape(h.shape[0], s // c, c, -1).transpose(1, 0, 2, 3)
-            tc = targets.reshape(targets.shape[0], s // c, c).transpose(
-                1, 0, 2)
-
-            @jax.checkpoint
-            def chunk_terms(h_c, t_c):
-                # telemetry stays off in here: stats pushed from inside the
-                # chunk scan could not legally escape its trace scope.
-                with telemetry.suppressed():
-                    logits = linear(h_c, w, plan.head_linear, cfg,
-                                    axes=("tokens", "embed", "vocab"))
-                return self._xent_terms(logits, t_c)
-
-            def body(carry, xs):
-                nll, z2, n = carry
-                d_nll, d_z2, d_n = chunk_terms(*xs)
-                return (nll + d_nll, z2 + d_z2, n + d_n), None
-
-            (nll, z2, n), _ = jax.lax.scan(
-                body, (jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.int32)), (hc, tc))
+            nll, z2, n = self._chunked_xent(params, h, targets, plan)
         denom = jnp.maximum(n, 1)
         loss = nll / denom
         metrics = {"loss": loss, "tokens": denom}

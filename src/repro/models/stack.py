@@ -25,6 +25,7 @@ bit-identically).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, List, Optional
 
@@ -52,6 +53,7 @@ from repro.models import ssm as ssm_lib
 from repro.nn.layers import apply_norm, shard_hint
 from repro.nn.params import ParamSpec
 from repro.telemetry import collect as telemetry
+from repro.telemetry.profiler import graph_span
 
 __all__ = ["layer_param_specs", "stack_param_specs", "run_stack",
            "stack_cache_spec", "init_stack_cache"]
@@ -235,7 +237,10 @@ def _run_layer(params, cfg: ModelConfig, spec: LayerSpec, row:
                     decode=decode, unroll=not cfg.scan_layers)
         if cache is not None:
             new_cache["self"] = c if c is not None else cache["self"]
-        x = x + out
+        # each residual add belongs to its sublayer's scope
+        with (graph_span("attn") if spec.mixer == "attn"
+              else contextlib.nullcontext()):
+            x = x + out
 
         if spec.cross:
             h = shard_hint(apply_norm(params["cross_norm"], x, cfg.norm),
@@ -246,15 +251,16 @@ def _run_layer(params, cfg: ModelConfig, spec: LayerSpec, row:
                 out, ccache = attn_lib.cross_attention(
                     params["cross"], cfg, h, row.attn_linear,
                     kv_states=cross_states, cache=cc)
-            gate = jnp.tanh(params["cross_gate"].astype(jnp.float32))
-            x = x + (out.astype(jnp.float32) * gate).astype(x.dtype)
+            with graph_span("attn"):
+                gate = jnp.tanh(params["cross_gate"].astype(jnp.float32))
+                x = x + (out.astype(jnp.float32) * gate).astype(x.dtype)
             if cache is not None:
                 new_cache["cross"] = ccache
 
         if spec.ffn == "dense":
             h = shard_hint(apply_norm(params["ffn_norm"], x, cfg.norm),
                            ("batch", "seq", "embed"))
-            with telemetry.module_scope("ffn"):
+            with telemetry.module_scope("ffn"), graph_span("mlp"):
                 x = x + mlp_lib.mlp(params["ffn"], cfg, h, row.ffn_linear)
         elif spec.ffn == "moe":
             h = shard_hint(apply_norm(params["ffn_norm"], x, cfg.norm),

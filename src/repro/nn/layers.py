@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core.qlinear import qlinear
 from repro.core.recipe import MatmulRecipe
+from repro.telemetry.profiler import graph_span
 
 __all__ = ["linear", "gelu", "silu", "relu2", "layer_norm", "rms_norm",
            "apply_norm", "rope", "sincos_positions", "shard_hint",
@@ -71,6 +72,7 @@ def layer_norm(x, scale, bias, eps=1e-5):
     return y.astype(x.dtype)
 
 
+@graph_span("norm")
 def apply_norm(params: dict, x, kind: str):
     if kind == "rmsnorm":
         return rms_norm(x, params["scale"])

@@ -1,19 +1,35 @@
-"""Measured-performance profiling: phase spans, step timing, MFU.
+"""Measured-performance profiling: spans, scopes, counters, step timing.
 
 The paper's efficiency claims are theoretical (speed factors derived from
 bit-widths); this module produces the *measured* side of that argument —
-per-phase trace annotations, wall-clock step-time percentiles, tokens/sec
-and MFU — so every perf change in the repo can be judged by wall clock
-(cf. Quartet's measured-throughput optimality argument, PAPERS.md).
+host spans and graph scopes a profiler trace can attribute time to, a
+process-wide compile count, wall-clock step-time percentiles, tokens/sec
+and MFU.
 
-Three pieces:
+Pieces:
 
-  * **Spans** — :func:`phase_span` wraps a *host-side* region of the train
-    loop in a ``jax.profiler.TraceAnnotation`` (visible as a named slice in
-    a captured trace); :func:`graph_span` is the *trace-time* counterpart
-    (``jax.named_scope``) used inside jitted code, so the quantize / fwd /
-    bwd / optim / collective regions carry their phase name into the HLO
-    metadata and any xprof / perfetto trace.
+  * **Host spans** — :func:`phase_span` wraps a *host-side* region in a
+    ``jax.profiler.TraceAnnotation`` (a named slice in a captured trace).
+    ``Trainer.train`` writes per step, each with the stat ``step_num``:
+    ``data`` (the batch to the device), ``step`` (the measured step)
+    holding ``dispatch`` (the call of the jitted step until it returns)
+    and ``wait`` (the ``block_until_ready`` on its loss), then ``host``
+    (metric reads and bookkeeping).  Each history row carries the same
+    boundaries as counters: ``t_host``, ``t_data``, ``t_dispatch``,
+    ``t_wait`` (seconds) and ``compiles`` (``Trainer`` defines them).
+    The serving runtime writes ``decode_prefill``, ``decode_insert`` and
+    ``decode_generate``.
+  * **Graph scopes** — :func:`graph_span` is the trace-time counterpart
+    (``jax.named_scope``) for jitted code: ops traced under it carry the
+    name in their HLO ``op_name`` metadata, through ``jvp``/``transpose``
+    and rematerialization.  The step graph's layer scopes are ``embed``,
+    ``attn``, ``mlp``, ``norm``, ``head`` (models) and ``optim`` (clipping
+    and the optimizer update, ``train_step``); ``collective`` marks the
+    fp8 gradient reduction (``analysis.qlint``'s comms audit reads it) and
+    ``qrole_*``/``qdq_*`` mark quantized matmul roles (``analysis.qlint``).
+  * **Compile count** — :func:`compile_count`: backend compilations in
+    this process (persistent-cache hits included), from one
+    ``jax.monitoring`` listener registered on first use.
   * **StepTimer** — rolling step-time statistics with correct device-sync
     discipline: callers time ``fn(...)`` to the ``block_until_ready`` of
     its outputs (``time_call`` does this for you), the first ``warmup``
@@ -33,9 +49,8 @@ Capturing a real trace around the annotated regions:
     with jax.profiler.trace("/tmp/trace"):   # or profiler server + xprof
         trainer.train(num_steps=20)
 
-then open the trace in TensorBoard/xprof — the ``data``/``step``/``host``
-host spans and the ``quantize``/``fwd``/``bwd``/``optim``/``grad_comms``
-graph scopes appear by name.
+then open the trace in TensorBoard/xprof: the host spans appear by name
+on the host's thread, and each device op's HLO metadata names its scopes.
 """
 from __future__ import annotations
 
@@ -47,30 +62,59 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 
-__all__ = ["phase_span", "graph_span", "percentiles", "StepTimer",
-           "train_step_flops", "device_peak_flops", "PHASES"]
-
-# Canonical phase names used by the trainer / train_step wiring; free-form
-# names are fine too, these just keep traces and reports consistent.
-PHASES = ("data", "quantize", "fwd", "bwd", "optim", "collective", "host")
+__all__ = ["phase_span", "graph_span", "compile_count", "percentiles",
+           "StepTimer", "train_step_flops", "device_peak_flops"]
 
 
 @contextlib.contextmanager
-def phase_span(name: str):
+def phase_span(name: str, **meta):
     """Host-side span: annotate a region of host code (data loading, the
     dispatch+sync of one step, controller/writer work) so it shows as a
-    named slice in a ``jax.profiler`` trace.  No-op overhead when no trace
-    is being captured (~sub-microsecond), so it is always on."""
-    with jax.profiler.TraceAnnotation(name):
+    named slice in a ``jax.profiler`` trace; ``meta`` (``step_num=...``)
+    becomes the slice's stats and leaves its name as it is.  No-op
+    overhead when no trace is being captured (~sub-microsecond), so it is
+    always on."""
+    with jax.profiler.TraceAnnotation(name, **meta):
         yield
 
 
 def graph_span(name: str):
     """Trace-time span for *jitted* code: a ``jax.named_scope`` context.
-    Ops traced under it carry ``name`` in their HLO metadata, which xprof
-    uses to attribute device time to phases (quantize/fwd/bwd/optim/...).
-    Pure metadata — the compiled computation is unchanged."""
+    Ops traced under it carry ``name`` in their HLO ``op_name`` metadata,
+    which maps device time to the layer that caused it.  Pure metadata —
+    the compiled computation is unchanged."""
     return jax.named_scope(name)
+
+
+class _CompileCount:
+    """Backend compilations seen by one process-wide ``jax.monitoring``
+    listener (JAX keeps listeners for the life of the process, so one is
+    registered, on first use, however many trainers read it)."""
+
+    def __init__(self):
+        self.n = 0
+        self.listening = False
+
+    def on_event(self, event: str, duration: float, **kw) -> None:
+        if event.endswith("backend_compile_duration"):
+            self.n += 1
+
+    def read(self) -> int:
+        if not self.listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                self.on_event)
+            self.listening = True
+        return self.n
+
+
+_COMPILES = _CompileCount()
+
+
+def compile_count() -> int:
+    """Backend compilations in this process so far: every executable JAX
+    builds, persistent-cache hits included.  Counting starts at the first
+    call; differences between two calls count what happened in between."""
+    return _COMPILES.read()
 
 
 def percentiles(xs: Sequence[float],

@@ -178,17 +178,15 @@ def make_train_step(model: Model, tcfg: TrainConfig,
     # collector, no probes, bit-identical graph.
     collector = telemetry.TelemetryCollector() if tcfg.telemetry else None
 
-    # Phase scopes (telemetry.profiler.graph_span = jax.named_scope) are
-    # pure HLO metadata: xprof attributes device time to fwd/bwd/optim/
-    # collective by name, and the compiled computation is unchanged.  The
-    # forward trace runs under bwd/fwd (value_and_grad traces it there);
-    # backward-only ops carry bwd alone.
+    # Scopes (telemetry.profiler.graph_span = jax.named_scope) are pure
+    # HLO metadata: the model's layers scope their own ops (embed, attn,
+    # mlp, norm, head); here ``optim`` holds clipping and the update and
+    # ``collective`` the fp8 gradient reduction.
     def loss_fn(params, batch):
-        with graph_span("fwd"):
-            return model.loss(params, batch, plan)
+        return model.loss(params, batch, plan)
 
     def loss_fn_tel(params, batch, probes):
-        with graph_span("fwd"), telemetry.collecting(collector, probes):
+        with telemetry.collecting(collector, probes):
             loss, metrics = model.loss(params, batch, plan)
             metrics = dict(metrics)
             metrics.update(collector.drain_root())
@@ -281,7 +279,7 @@ def make_train_step(model: Model, tcfg: TrainConfig,
 
         def sharded_grads(params, comp_state, batch):
             batch_dp = jax.tree.map(_split_dp, batch)
-            with graph_span("bwd"), layers.sharding_context(inner_rules):
+            with layers.sharding_context(inner_rules):
                 grads_dp, metrics_dp = jax.vmap(
                     compute_grads, in_axes=(None, 0))(params, batch_dp)
             with graph_span("collective"):
@@ -306,13 +304,14 @@ def make_train_step(model: Model, tcfg: TrainConfig,
                     params, comp_state, batch)
                 if collector is not None:
                     metrics.update(telemetry.grad_norm_metrics(grads))
-                grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+                with graph_span("optim"):
+                    grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
             else:
-                with graph_span("bwd"):
-                    grads, metrics = compute_grads(params, batch)
+                grads, metrics = compute_grads(params, batch)
                 if collector is not None:
                     metrics.update(telemetry.grad_norm_metrics(grads))
-                grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+                with graph_span("optim"):
+                    grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
                 if use_compression:
                     with graph_span("collective"):
                         grads, comp_state = fp8_compress_grads(grads,

@@ -26,10 +26,20 @@ Responsibilities:
     steps are emitted as ``{"event": "straggler", ...}`` JSONL events;
   * measured-performance observability: every step is timed with device
     sync into a ``telemetry.profiler.StepTimer`` (``step_time_summary()``
-    reports p50/p95/p99, tokens/sec, and MFU on a TPU), the loop's data/step/host
-    regions carry ``jax.profiler`` phase spans, and the JSONL stream goes
-    through the host-offloaded ``AsyncJsonlWriter`` (bounded queue +
-    writer thread) so logging never blocks the step;
+    reports p50/p95/p99, tokens/sec, and MFU on a TPU); each step writes
+    the ``jax.profiler`` host spans ``data``, ``step`` (holding
+    ``dispatch`` and ``wait``) and ``host``, each with the stat
+    ``step_num``; each history row carries the same boundaries as
+    counters: ``t_host`` (from the previous step's sync to this step's
+    start: its bookkeeping, the loop, and whatever the caller did between
+    ``train`` calls; 0 on a trainer's first step), ``t_data``,
+    ``t_dispatch``, ``t_wait`` (seconds, ``time.perf_counter``; the four
+    add up to the wall time since the previous step's sync, and
+    ``t_dispatch + t_wait`` is ``dt``) and ``compiles`` (backend
+    compilations during the step, ``telemetry.profiler.compile_count``);
+    and the JSONL stream goes through the host-offloaded
+    ``AsyncJsonlWriter`` (bounded queue + writer thread) so logging never
+    blocks the step;
   * eval + metrics history; optional JSONL telemetry log
     (``TrainConfig.telemetry_jsonl``);
   * mesh-native SPMD: pass ``rules=ShardingRules(...)`` (or set
@@ -60,8 +70,9 @@ from repro.distributed.sharding import ShardingRules, default_rules
 from repro.models.model import Model
 from repro.optim import init_compression_state
 from repro.telemetry.controller import PrecisionController
-from repro.telemetry.profiler import (StepTimer, device_peak_flops,
-                                      phase_span, train_step_flops)
+from repro.telemetry.profiler import (StepTimer, compile_count,
+                                      device_peak_flops, phase_span,
+                                      train_step_flops)
 from repro.telemetry.writer import AsyncJsonlWriter
 from repro.train.train_step import (make_optimizer, make_train_step,
                                     train_step_shardings)
@@ -150,6 +161,8 @@ class Trainer:
         if tcfg.telemetry_jsonl:
             self.writer = AsyncJsonlWriter(tcfg.telemetry_jsonl)
         self.timer = StepTimer(warmup=tcfg.profiler_warmup)
+        self._t_synced: Optional[float] = None   # clock at the last sync
+        compile_count()   # the listener must be on before the first step
 
     # ------------------------------------------------------------------
 
@@ -280,28 +293,42 @@ class Trainer:
                 self.tcfg.telemetry_every <= 1
                 or step % self.tcfg.telemetry_every == 0)
             fn = self._step_fn(plan, telemetry=tel_on)
-            with phase_span("data"):
-                batch = {k: jnp.asarray(v)
-                         for k, v in self.pipeline.batch(step).items()}
             lr_scale = (self.controller.lr_scale
                         if self.controller is not None else 1.0)
+            compiles = compile_count()
+            t_start = time.perf_counter()
+            with phase_span("data", step_num=step):
+                batch = {k: jnp.asarray(v)
+                         for k, v in self.pipeline.batch(step).items()}
             # dispatch + device sync is the measured step: block on an
             # output before reading the clock so dt is the device step
             # time, not just the host dispatch.
-            with phase_span("step"):
+            with phase_span("step", step_num=step):
                 t0 = time.perf_counter()
-                params, opt_state, comp_state, metrics = fn(
-                    state.params, state.opt_state, state.comp_state, batch,
-                    jnp.asarray(step, jnp.int32),
-                    jnp.asarray(lr_scale, jnp.float32))
-                jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
+                with phase_span("dispatch", step_num=step):
+                    params, opt_state, comp_state, metrics = fn(
+                        state.params, state.opt_state, state.comp_state,
+                        batch, jnp.asarray(step, jnp.int32),
+                        jnp.asarray(lr_scale, jnp.float32))
+                t1 = time.perf_counter()
+                with phase_span("wait", step_num=step):
+                    jax.block_until_ready(metrics["loss"])
+                t2 = time.perf_counter()
+            dt = t2 - t0
+            # the loop's wall time since the last step's sync, split so the
+            # four parts add up to it exactly
+            phases = {"t_host": (0.0 if self._t_synced is None
+                                 else t_start - self._t_synced),
+                      "t_data": t0 - t_start, "t_dispatch": t1 - t0,
+                      "t_wait": t2 - t1,
+                      "compiles": compile_count() - compiles}
+            self._t_synced = t2
             self.timer.record(dt)
             straggler = self.monitor.record(step, dt)
             state = TrainState(params, opt_state, comp_state, step + 1)
             # everything below is host-side bookkeeping, off the device
             # critical path (the async writer never blocks here)
-            with phase_span("host"):
+            with phase_span("host", step_num=step):
                 if straggler:
                     log(f"[straggler] step {step} took {dt:.2f}s "
                         f"(ema {self.monitor.ema:.2f}s)")
@@ -315,6 +342,7 @@ class Trainer:
                 row["recipe"] = plan.name
                 row["dt"] = dt
                 row["straggler"] = straggler
+                row.update(phases)
                 self.history.append(row)
                 if self.writer is not None:
                     self.writer.write(row)
