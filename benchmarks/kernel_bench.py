@@ -55,9 +55,8 @@ def _bench_fused_roles(x, w, recipe, tag: str) -> None:
     """Time the Pallas pipelines vs unfused qmatmul for all three training
     matmuls: fwd via the primal, dgrad+wgrad via the VJP.
 
-    ``pallas_fused`` is the two-pass reference pipeline (quantize to HBM,
-    then the tiled matmul — the historical meaning of the entry, kept so
-    the committed baseline stays comparable); ``pallas_stream`` is the
+    ``pallas_fused`` is the two-pass pipeline, the default (quantize each
+    operand to HBM once, then XLA's dot); ``pallas_stream`` is the
     single-pass streaming pipeline (quantized K-panels live in VMEM and are
     consumed directly by the MXU loop).  The stream rows carry a
     ``speedup_vs_two_pass`` derived field so the overlap win is measured,
@@ -97,12 +96,11 @@ def _bench_fused_roles(x, w, recipe, tag: str) -> None:
 
 
 def _bench_stream_overlap(x, w, tag: str) -> None:
-    """Both pipelines pinned to the same fixed (128, 128, 128) tiling —
-    the constrained multi-tile regime real VMEM budgets force on TPU (the
-    autotuned whole-dim tiles reduce both pipelines to one grid step each,
-    where the comparison degenerates).  two_pass walks a quantize grid AND
-    a matmul grid with an HBM round-trip between them; stream walks one
-    fused grid with both operand caches live.  NOTE: interpret mode prices
+    """The streaming kernel pinned to a fixed (128, 128, 128) tiling — the
+    constrained multi-tile regime real VMEM budgets force on TPU — against
+    the two-pass pipeline, which takes no tiles: a quantize grid per
+    operand with an HBM round-trip, then XLA's dot; stream walks one fused
+    grid with both operand caches live.  NOTE: interpret mode prices
     emulated op count, not launches or HBM traffic, so the CPU ratio here
     is a trend anchor for the TPU re-measurement (ROADMAP item 3), not a
     speedup claim."""

@@ -405,9 +405,10 @@ def audit_graph_vs_census(graph: Dict[str, Any],
                           ) -> List[Finding]:
     """Cross-check the jaxpr census against the routing census.
 
-    Every role with pallas-routed cells must stage at least as many
-    ``pallas_call`` equations as it has distinct cells (remat/unroll can
-    only add replays, never remove calls); every ``qdq_*`` marker under a
+    Every role with pallas-routed cells that quantize an operand (or run
+    the streaming kernel) must stage at least as many ``pallas_call``
+    equations as it has such cells (remat/unroll can only add replays,
+    never remove calls); every ``qdq_*`` marker under a
     ``qrole_*`` scope must be explained by a QDQ-routed census cell of
     that role (an unexplained one means a quantize op reached a path the
     census never sanctioned); f32 operands on kernel calls are
@@ -417,7 +418,10 @@ def audit_graph_vs_census(graph: Dict[str, Any],
     pallas_cells = Counter()
     allowed_markers = set()
     for ev in cells:
-        if ev.route == "pallas":
+        # A bf16 x bf16 cell off the streaming pipeline is XLA's dot alone.
+        if ev.route == "pallas" and (ev.pipeline == "stream"
+                                     or ev.mode_a != "pass"
+                                     or ev.mode_b != "pass"):
             pallas_cells[ev.role] += 1
         if ev.route in ("qdq", "qdq_fallback", "dot", "packed_dot"):
             for spec_str in (ev.spec_a, ev.spec_b):

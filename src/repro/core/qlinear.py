@@ -13,16 +13,16 @@ Two implementations share the same recipe semantics:
 
   * ``qmatmul``        — unfused QDQ + ``lax.dot`` (simulation reference);
   * ``pallas_qmatmul`` — fwd, dgrad and wgrad each run through the fused
-    Pallas pipeline (``kernels.fp4_matmul.fused_qmm``; streaming
-    quantize-into-the-MXU-loop single pass by default, autotuned tiling),
-    with transposed-operand variants so the backward matmuls quantize
+    Pallas pipeline (``kernels.fp4_matmul.fused_qmm``; by default one
+    Pallas quantize pass per operand, then XLA's dot), with
+    transposed-operand variants so the backward matmuls quantize
     relative to their own reduction axes without materializing
     ``w^T``/``x^T`` in HBM.  Stochastic-rounding specs are
     kernel-realizable (in-kernel PRNG noise seeded from ``key_data``);
     roles the kernel cannot realize (fp16 clipping, non-128 blocks) fall
     back to the QDQ path for that role only.
-  * ``pallas_qmatmul_two_pass`` — the same contract pinned to the PR-3
-    two-pass reference pipeline (bit-identical at equal tiling).
+  * ``pallas_qmatmul_two_pass`` — the same contract pinned to the
+    two-pass pipeline, whatever the process default.
 
 The public entry point ``qlinear`` folds arbitrary leading batch dims and
 selects the implementation via ``impl`` ('qdq' | 'pallas' |
@@ -228,8 +228,8 @@ def _dot_fused(a: jnp.ndarray, b: jnp.ndarray,
     apply in effective orientation (reduction-relative).  Stochastic specs
     consume ``key_data`` through the kernel's in-kernel PRNG (different
     stream than the QDQ path's ``jax.random`` — statistically equivalent,
-    not bit-equal).  ``pipeline``: None = the process default (streaming
-    single-pass unless overridden via ``use_pipeline``, resolved at trace
+    not bit-equal).  ``pipeline``: None = the process default (two-pass
+    unless overridden via ``use_pipeline``, resolved at trace
     time), or an explicit ``kernels.fp4_matmul.PIPELINES`` name.  With
     ``collect_stats`` returns ``(y, (sa, sb))`` raw quantize stat vectors
     (None for pass/fallback operands).
@@ -301,16 +301,17 @@ def _make_pallas_qmatmul(pipeline: Optional[str]):
 pallas_qmatmul, _pallas_qmatmul_bwd = _make_pallas_qmatmul(None)
 pallas_qmatmul.__doc__ = (
     """``qmatmul`` with all three matmuls (fwd/dgrad/wgrad) running through
-    the fused quantize+matmul Pallas kernel (default pipeline: streaming
-    single-pass; see ``kernels.fp4_matmul``).  Same signature/semantics.
+    the fused quantize+matmul pipeline (default: a quantize pass per
+    operand, then XLA's dot; see ``kernels.fp4_matmul``).  Same
+    signature/semantics.
     ``axes`` only steers the QDQ-fallback roles (kernel scales live in
     kernel-private buffers and need no placement).""")
 
 pallas_qmatmul_two_pass, _ = _make_pallas_qmatmul("two_pass")
 pallas_qmatmul_two_pass.__doc__ = (
-    """``pallas_qmatmul`` pinned to the two-pass reference pipeline
-    (quantize pass + matmul pass) — bit-identical to the streaming default
-    at equal tiling; kept selectable for A/B measurement and debugging.""")
+    """``pallas_qmatmul`` pinned to the two-pass pipeline (quantize pass
+    + XLA's dot) whatever the process default; the streaming pipeline
+    differs from it only in the f32 accumulation order along K.""")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -386,9 +387,9 @@ def packed_linear(x: jnp.ndarray, w: PackedTensor, recipe: MatmulRecipe,
 
       * passthrough activation spec -> plain dot (weight-only serving);
       * pallas impls with a kernel-realizable activation spec -> the
-        fused stream pipeline via ``kernels.ops``, RHS in mode ``pass``
-        (the kernel quantizes only the activations and streams the
-        pre-quantized K-panels straight into the MXU loop);
+        fused pipeline via ``kernels.ops``, RHS in mode ``pass`` (the
+        quantize pass covers only the activations; the pre-quantized
+        weight goes to the dot as it is);
       * otherwise -> QDQ fallback for the activation side only.
 
     Forward-only by design (serving): gradients, telemetry taps and the

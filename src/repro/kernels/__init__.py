@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
-  fp4_matmul        quantize-once K-panel pipeline: per-operand quantize
-                    pass + decoupled-tiling MXU matmul (the §3.2 FFN)
+  fp4_matmul        quantize-once pipeline: per-operand Pallas quantize
+                    pass + XLA's dot (the §3.2 FFN)
   rounding          shared bit-exact integer RTN / stochastic-rounding
                     codec + counter-hash noise (single source of truth)
   quantize          standalone per-tile quantizer

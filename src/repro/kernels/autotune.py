@@ -26,14 +26,15 @@ Three layers:
   *ignores* table entries whose tiles don't divide the shape, so a stale
   table can never break a matmul — only fail to speed it up.
 
-Tile resolution is value-safe: the kernels are bit-identical across any
-``(bm, bn)`` (output tiling never touches the math), and ``bk`` only
-changes the f32 accumulation *order* — exactly as it does in the two-pass
-reference at the same ``bk``.  Quantized values, stats and SR draws never
-depend on the tiling (quant groups are ``block``-sized and SR noise is
-keyed by global element coordinate), so a table hit vs a heuristic
-fallback differs at most in dot-product summation order, never in any
-quantization decision.
+The table steers only the streaming pipeline (``pipeline="stream"``);
+the default two-pass pipeline runs its product on XLA's dot and takes no
+tiles.  Tile resolution is value-safe: the kernel is bit-identical across
+any ``(bm, bn)`` (output tiling never touches the math), and ``bk`` only
+changes the f32 accumulation *order*.  Quantized values, stats and SR
+draws never depend on the tiling (quant groups are ``block``-sized and SR
+noise is keyed by global element coordinate), so a table hit vs a
+heuristic fallback differs at most in dot-product summation order, never
+in any quantization decision.
 """
 from __future__ import annotations
 

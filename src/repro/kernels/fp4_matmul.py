@@ -36,48 +36,45 @@ clip/underflow/rel-err/scale-spread accumulators ride in VMEM scratch and
 are emitted as one (1, 8) vector, replacing the full re-QDQ that
 ``telemetry.tap_matmul`` used to pay (see ``finalize_quant_stats``).
 
-**Phase 2 — matmul pass** (``_tiled_matmul``).  A plain tiled MXU matmul
-over the quantize-pass outputs with grid tiling ``(bm, bn, bk)`` fully
-**decoupled** from the 128-element quant group — multiple quant groups per
-MXU tile, fewer grid steps, zero re-quantization.  K stays innermost and
-accumulates into an f32 VMEM scratch; ``pass``-mode (unquantized bf16)
-operands skip phase 1 entirely and are read transposed via BlockSpec index
-maps, exactly as before.
+**Phase 2 — the product** (``_xla_dot``).  XLA's own dot of the
+quantize-pass outputs: bf16 x bf16 products with an f32 accumulator, the
+result cast to the input dtype.  Its dimension numbers read ``pass``-mode
+(unquantized) operands in their stored layout, so a bf16 x bf16 role
+(the paper's FFN dgrad) runs no kernel at all.  This is the default
+pipeline, ``two_pass``, for every granularity pair and shape: on a v5e
+XLA's dot runs these shapes near the MXU's bf16 rate, while a quantize
+pass costs a few bytes of HBM an element, once.
 
-**Single-pass streaming pipeline** (``_stream_kernel``, the default since
-the overlap round).  The two-pass split still paid a full HBM round-trip
-of the dequantized K-panels between the phases.  The streaming pipeline is
-ONE ``pallas_call`` whose grid walks ``(M/bm, N/bn)`` output tiles with K
-innermost: each K-step's operand tiles are fetched by the grid pipeline
-(double-buffered HBM->VMEM DMA, Pallas' standard prefetch), quantized
-in-registers/VMEM, and consumed directly by the MXU accumulation — the
-quantize work rides inside the GEMM's dataflow (the quantize-fused-GEMM
-unit of cost of Quartet and "Optimizing LLM Training Using FP4
-Quantization") and the dequantized panels never touch HBM.  The LHS row
-panel is additionally cached in a VMEM scratch across the ``N/bn``
-output-column revisits (quantized exactly once, weight-stationary style)
-and the quantized RHS across the ``M/bm`` output-row revisits, each under
-its own VMEM budget; past the budgets, tiles re-quantize per revisit —
-recompute that overlaps the MXU on hardware.
+**Single-pass streaming pipeline** (``_stream_kernel``, reachable only
+through ``pipeline="stream"`` or ``use_pipeline("stream")``).  ONE
+``pallas_call`` whose grid walks ``(M/bm, N/bn)`` output tiles with K
+innermost: each K-step's operand tiles are fetched by the grid pipeline,
+quantized in VMEM and consumed directly by the MXU accumulation, so the
+dequantized panels never touch HBM.  The LHS row panel is cached in a
+VMEM scratch across the ``N/bn`` output-column revisits and the quantized
+RHS across the ``M/bm`` output-row revisits, each under its own VMEM
+budget; past the budgets, tiles re-quantize per revisit.  On a v5e that
+requantization is VPU work (the chip has no bf16 VPU arithmetic) that
+sets the kernel's pace, which is why it is not the default.
 Because the codec is the bit-exact integer RTN of ``kernels.rounding`` and
 SR noise is keyed by each element's *global* coordinate, re-quantizing a
-tile reproduces the quantize pass bit-for-bit: for the same ``(bm, bn,
-bk)`` the streaming output ``y`` (and the telemetry epilogue's counter /
-extrema lanes) is **bit-identical** to the two-pass pipeline, which stays
-selectable as the reference implementation (``pipeline='two_pass'`` /
-``use_pipeline``).  ``token``/``tensor`` granularities need their
-whole-reduction-axis amax sweep before any element can quantize, so those
-roles route through the two-pass pipeline automatically.
+tile reproduces the quantize pass bit-for-bit: the streaming pipeline's
+telemetry stats are **bit-identical** to the two-pass pipeline's, and so
+is ``y`` where a single K step (``bk == K``) leaves the two dots the same
+f32 accumulation order; otherwise ``y`` differs in that order only.
+``token``/``tensor`` granularities need their whole-reduction-axis amax
+sweep before any element can quantize, so those roles route through the
+two-pass pipeline automatically.
 
 ``fused_qmm`` orchestrates the pipelines and keeps its role-parameterized
 contract: per-operand modes ``pass | block | tile | token | tensor``,
 ``trans_a``/``trans_b`` stored-layout transposition, per-operand formats
 and pow2-scale flags, plus per-operand ``sr`` flags and seeds.  Tile
-knobs: ``block`` (quant group, 128), ``bm``/``bn``/``bk`` (MXU tiling —
-when all three are omitted the persistent autotuning table
-(``kernels.autotune``, committed ``tuning_table.json``, populated by
-``kernel_bench --autotune``) is consulted first, falling back to the
-``_pick_tile`` heuristic on a miss).
+knobs: ``block`` (quant group, 128), ``bm``/``bn``/``bk`` (the streaming
+kernel's MXU tiling — when all three are omitted the persistent
+autotuning table (``kernels.autotune``, committed ``tuning_table.json``,
+populated by ``kernel_bench --autotune``) is consulted first, falling
+back to the ``_pick_tile`` heuristic on a miss).
 """
 from __future__ import annotations
 
@@ -102,10 +99,10 @@ __all__ = ["fp4_matmul", "fused_qmm", "quantize_panels", "interpret_mode",
 
 QUANT_MODES = ("pass", "block", "tile", "token", "tensor")
 
-# Matmul pipelines: "stream" = single-pass quantize->MXU fusion (default),
-# "two_pass" = the PR-3 quantize-pass + matmul-pass reference.  token/tensor
-# granularities always take two_pass (see stream_supported).
-PIPELINES = ("stream", "two_pass")
+# Matmul pipelines: "two_pass" = quantize pass + XLA's dot (the default),
+# "stream" = single-pass quantize->MXU fusion.  token/tensor granularities
+# always take two_pass (see stream_supported).
+PIPELINES = ("two_pass", "stream")
 
 # LHS row-panel VMEM cache budget for the streaming kernel: the quantized
 # (bm, K) panel is kept in scratch across N/bn output-column revisits when it
@@ -166,7 +163,7 @@ def _pick_tile(dim: int, block: int = 128) -> int:
 # Stack-shaped so nested `use_pipeline` contexts unwind correctly; the
 # resolution happens OUTSIDE the jit boundary (`fused_qmm` is a plain python
 # wrapper), so flipping the pipeline can never serve a stale jit cache.
-_pipeline_stack = ["stream"]
+_pipeline_stack = ["two_pass"]
 
 
 def default_pipeline() -> str:
@@ -308,7 +305,7 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
     the LHS, (N, K) for the RHS — groups always reduce along axis 1 here.
     ``trans`` transposes the stored read into that orientation in VMEM;
     ``emit_trans`` transposes the write back out (the RHS emits (K, N) so
-    the matmul pass reads it plain).
+    the product reads it plain).
 
     ``grid_kind``: 'one' = single sweep, grid (panels, ktiles) — block/tile
     groups live inside a tile.  'token' = grid (panels, 2, ktiles), sweep 0
@@ -593,61 +590,24 @@ def quantize_panels(t: jnp.ndarray, *, mode: str = "block",
 
 
 # ---------------------------------------------------------------------------
-# Phase 2: tiled matmul pass (no quantization left in here)
+# Phase 2: the product on XLA's dot (no quantization left in here)
 # ---------------------------------------------------------------------------
 
-def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k, trans_a, trans_b):
-    """One (bm, bn) output tile at K-step pl.program_id(2)."""
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _xla_dot(a: jnp.ndarray, b: jnp.ndarray, *, trans_a: bool,
+             trans_b: bool) -> jnp.ndarray:
+    """y = A' @ B' with an f32 accumulator, cast to ``a.dtype``.
 
-    at = a_ref[...]
-    if trans_a:
-        at = at.T
-    bt = b_ref[...]
-    if trans_b:
-        bt = bt.T
-    acc_ref[...] += _mxu_dot(at, bt)
-
-    @pl.when(pl.program_id(2) == n_k - 1)
-    def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _tiled_matmul(a: jnp.ndarray, b: jnp.ndarray, *, trans_a: bool,
-                  trans_b: bool, bm: int, bn: int, bk: int,
-                  interpret: bool) -> jnp.ndarray:
-    """y = A' @ B' with (bm, bn, bk) MXU tiling, f32 VMEM accumulation.
-
-    Operands arrive either pre-quantized in effective orientation (trans
-    flag False) or as ``pass``-mode stored arrays read transposed via the
-    BlockSpec index maps (no HBM transpose, as before).
+    The arithmetic of ``_mxu_dot``: bf16 operands pin DEFAULT precision
+    (their products are exact in f32); wider operands take HIGHEST, the
+    full-precision contract a Mosaic dot gives them.  Stored layouts are
+    read through the dimension numbers, so no transpose is materialized.
     """
-    m, k = (a.shape[1], a.shape[0]) if trans_a else a.shape
-    kb, n = (b.shape[1], b.shape[0]) if trans_b else b.shape
-    assert k == kb, (a.shape, b.shape, trans_a, trans_b)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
-    kernel = functools.partial(_mm_kernel, n_k=k // bk, trans_a=trans_a,
-                               trans_b=trans_b)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm, n // bn, k // bk),
-        in_specs=[
-            pl.BlockSpec((bk, bm),
-                         (lambda i, j, kk: (kk, i))) if trans_a
-            else pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bk),
-                         (lambda i, j, kk: (j, kk))) if trans_b
-            else pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(a, b)
+    prec = (jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
+            else jax.lax.Precision.HIGHEST)
+    dims = (((0 if trans_a else 1,), (1 if trans_b else 0,)), ((), ()))
+    return jax.lax.dot_general(
+        a, b, dims, precision=prec,
+        preferred_element_type=jnp.float32).astype(a.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -969,7 +929,8 @@ def _fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
                a_pow2: bool, b_pow2: bool, a_sr: bool, b_sr: bool,
                seed_a: Optional[jnp.ndarray], seed_b: Optional[jnp.ndarray],
                trans_a: bool, trans_b: bool, block: int,
-               bm: int, bn: int, bk: int, pipeline: str,
+               bm: Optional[int], bn: Optional[int], bk: Optional[int],
+               pipeline: str,
                real_dims: Optional[Tuple[int, int, int]],
                collect_stats: bool, interpret: bool):
     """Jit'd pipeline body — every knob arrives concrete (see fused_qmm)."""
@@ -980,11 +941,12 @@ def _fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
     assert k == kb, (a.shape, b.shape, trans_a, trans_b)
     assert m % block == 0 and k % block == 0 and n % block == 0, \
         (m, k, n, block)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     mr, kr, nr = real_dims if real_dims is not None else (m, k, n)
 
     if pipeline == "stream":
         assert stream_supported(a_mode, b_mode), (a_mode, b_mode)
+        assert m % bm == 0 and n % bn == 0 and k % bk == 0, \
+            (m, n, k, bm, bn, bk)
         y, (stats_a, stats_b) = _stream_qmm(
             a, b, a_mode=a_mode, b_mode=b_mode,
             fmt_a=FORMATS[a_fmt], fmt_b=FORMATS[b_fmt],
@@ -1003,7 +965,8 @@ def _fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
     stats_a = stats_b = None
     mm_trans_a, mm_trans_b = trans_a, trans_b
     if a_mode != "pass":
-        # LHS quant orientation (M, K) == effective orientation.
+        # LHS quant orientation (M, K) == effective orientation; the
+        # product reads it plain.
         a, stats_a = _quantize_operand(
             a, mode=a_mode, fmt=FORMATS[a_fmt], pow2=a_pow2,
             sr=a_sr and not FORMATS[a_fmt].passthrough, seed=seed_a,
@@ -1021,8 +984,7 @@ def _fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
             k_real=kr, collect_stats=collect_stats, interpret=interpret)
         mm_trans_b = False
 
-    y = _tiled_matmul(a, b, trans_a=mm_trans_a, trans_b=mm_trans_b,
-                      bm=bm, bn=bn, bk=bk, interpret=interpret)
+    y = _xla_dot(a, b, trans_a=mm_trans_a, trans_b=mm_trans_b)
     if collect_stats:
         return y, (stats_a, stats_b)
     return y
@@ -1047,38 +1009,45 @@ def fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
     Effective shapes A': (M, K), B': (K, N); all dims must be multiples of
     ``block`` (the ops.py wrapper pads).
 
-    ``pipeline`` picks the implementation: ``"stream"`` (default via
-    ``default_pipeline``/``use_pipeline``) fuses quantize into the MXU loop
-    in ONE pallas_call; ``"two_pass"`` is the quantize-pass + matmul-pass
-    reference.  Both are bit-identical for the same ``(bm, bn, bk)``;
-    token/tensor granularities silently take two_pass (stream_supported).
+    ``pipeline`` picks the implementation: ``"two_pass"`` (the default
+    via ``default_pipeline``/``use_pipeline``) quantizes each operand once
+    and runs the product on XLA's dot; ``"stream"`` fuses quantize into
+    the MXU loop in ONE pallas_call.  Their quantized values and stats are
+    bit-identical; ``y`` differs only in the f32 accumulation order along
+    K.  token/tensor granularities silently take two_pass
+    (stream_supported).
 
-    Tiling: explicit ``bm``/``bn``/``bk`` win; when ALL are omitted the
-    autotuning table (``kernels.autotune``) is consulted, falling back to
-    the ``_pick_tile`` heuristic on a miss (partially-specified tiles skip
-    the table).  This wrapper is deliberately NOT jit'd: pipeline and tile
-    resolution happen per call, outside the jit boundary, so a flipped
-    default pipeline or an updated tuning table can never serve a stale jit
-    cache — the resolved static knobs key ``_fused_qmm``'s cache.
+    Tiling (the streaming kernel's only): explicit ``bm``/``bn``/``bk``
+    win; when ALL are omitted the autotuning table (``kernels.autotune``)
+    is consulted, falling back to the ``_pick_tile`` heuristic on a miss
+    (partially-specified tiles skip the table).  This wrapper is
+    deliberately NOT jit'd: pipeline and tile resolution happen per call,
+    outside the jit boundary, so a flipped default pipeline or an updated
+    tuning table can never serve a stale jit cache — the resolved static
+    knobs key ``_fused_qmm``'s cache.
 
     ``a_sr``/``b_sr`` enable in-kernel stochastic rounding (seeds
     required); ``real_dims`` = unpadded (M, K, N) for stats masking; with
     ``collect_stats`` returns ``(y, (stats_a, stats_b))`` where pass-mode
     slots are None.
     """
-    m, k = (a.shape[1], a.shape[0]) if trans_a else a.shape
-    _, n = (b.shape[1], b.shape[0]) if trans_b else b.shape
     pipeline = resolve_pipeline(pipeline, a_mode, b_mode)
-    if bm is None and bn is None and bk is None:
-        from repro.kernels import autotune  # lazy: autotune imports us
-        hit = autotune.resolve_tiles(
-            m, n, k, dtypes=(a.dtype.name, b.dtype.name),
-            modes=(a_mode, b_mode), trans=(trans_a, trans_b), block=block)
-        if hit is not None:
-            bm, bn, bk = hit
-    bm = bm if bm is not None else _pick_tile(m, block)
-    bn = bn if bn is not None else _pick_tile(n, block)
-    bk = bk if bk is not None else _pick_tile(k, block)
+    if pipeline == "stream":
+        m, k = (a.shape[1], a.shape[0]) if trans_a else a.shape
+        _, n = (b.shape[1], b.shape[0]) if trans_b else b.shape
+        if bm is None and bn is None and bk is None:
+            from repro.kernels import autotune  # lazy: autotune imports us
+            hit = autotune.resolve_tiles(
+                m, n, k, dtypes=(a.dtype.name, b.dtype.name),
+                modes=(a_mode, b_mode), trans=(trans_a, trans_b),
+                block=block)
+            if hit is not None:
+                bm, bn, bk = hit
+        bm = bm if bm is not None else _pick_tile(m, block)
+        bn = bn if bn is not None else _pick_tile(n, block)
+        bk = bk if bk is not None else _pick_tile(k, block)
+    else:
+        bm = bn = bk = None
     return _fused_qmm(a, b, a_mode=a_mode, b_mode=b_mode, a_fmt=a_fmt,
                       b_fmt=b_fmt, a_pow2=a_pow2, b_pow2=b_pow2, a_sr=a_sr,
                       b_sr=b_sr, seed_a=seed_a, seed_b=seed_b,

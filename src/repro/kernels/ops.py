@@ -63,7 +63,7 @@ def pallas_qmm(a: jnp.ndarray, b: jnp.ndarray,
                interpret: Optional[bool] = None,
                role: Optional[str] = None, cell=None):
     """Per-role quantized matmul ``Q(A') @ Q(B')`` through the fused
-    pipeline (streaming single-pass by default, two-pass as reference —
+    pipeline (a quantize pass per operand, then XLA's dot, by default —
     see ``kernels.fp4_matmul``), with padding.
 
     ``a``/``b`` are stored arrays; ``A' = a^T`` under ``trans_a`` (same for
@@ -71,11 +71,12 @@ def pallas_qmm(a: jnp.ndarray, b: jnp.ndarray,
     quantize in effective orientation.  Quantization (``mode_*`` from
     ``core.qlinear.kernel_quant_mode``) is relative to the *effective*
     orientation, i.e. each backward matmul's own reduction axis; ``token``/
-    ``tensor`` amax needs its whole-axis sweep and automatically routes
-    through the two-pass pipeline.  Stochastic specs draw in-kernel noise
-    seeded from ``key_data``+``salt``.  ``pipeline``/``bm``/``bn``/``bk``
-    pass straight through to ``fused_qmm`` (None = default pipeline +
-    autotuned-or-heuristic tiles).
+    ``tensor`` amax needs its whole-axis sweep, so such roles take the
+    two-pass pipeline even where ``pipeline="stream"`` is asked for.
+    Stochastic specs draw in-kernel noise seeded from ``key_data`` +
+    ``salt``.  ``pipeline``/``bm``/``bn``/``bk`` pass straight through to
+    ``fused_qmm`` (None = the default pipeline; the tiles steer only the
+    streaming kernel).
     Padding semantics: zero K-padding adds nothing to the dot and leaves
     real rows' amax groups unchanged; padded M/N rows/cols quantize on the
     eps-floor scale path and are sliced away.  With ``collect_stats``

@@ -121,7 +121,8 @@ def test_table_tiling_is_applied_and_bit_identical(monkeypatch):
     ka, kb = jax.random.split(jax.random.PRNGKey(0))
     a = jax.random.normal(ka, (m, k), jnp.float32)
     b = jax.random.normal(kb, (k, n), jnp.float32) * 0.05
-    kw = dict(a_mode="block", b_mode="tile", interpret=True)
+    kw = dict(a_mode="block", b_mode="tile", pipeline="stream",
+              interpret=True)
 
     applied = {}
     orig = fm._fused_qmm
@@ -161,5 +162,6 @@ def test_partial_explicit_tiles_skip_the_table():
     set_table(_table(key, 128, 128, 128))
     resolve_tiles.cache_clear()
     before = dict(COUNTERS)
-    fused_qmm(a, b, a_mode="block", b_mode="tile", bm=256, interpret=True)
+    fused_qmm(a, b, a_mode="block", b_mode="tile", bm=256,
+              pipeline="stream", interpret=True)
     assert dict(COUNTERS) == before, "partial tiles must skip the lookup"

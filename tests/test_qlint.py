@@ -198,6 +198,36 @@ def test_train_graph_audit_clean_and_covers_all_cells():
         <= report.summary["recompile"]["budget"]
 
 
+def test_bf16_dgrad_cells_stage_no_kernel():
+    """The paper's bf16 x bf16 FFN dgrad is a pallas-routed cell that the
+    two-pass pipeline runs on XLA's dot alone: the census asks no
+    pallas_call of it, while a quantizing cell of the role still needs
+    one, and so does a bf16 cell on the streaming kernel."""
+    from repro.core.routing import RouteEvent
+    bf16 = RouteEvent("L0", "ffn", "dgrad", "pallas", "bf16", "bf16",
+                      mode_a="pass", mode_b="pass", pipeline="two_pass")
+    fp8 = RouteEvent("L0", "attn", "dgrad", "pallas", "fp8_e4m3@token",
+                     "fp8_e4m3@token", mode_a="token", mode_b="token",
+                     pipeline="two_pass")
+    audit = qlint.audit_graph_vs_census
+    assert audit({"pallas_calls": {}}, [bf16]) == []
+    assert audit({"pallas_calls": {"dgrad": 2}}, [bf16, fp8]) == []
+    short = audit({"pallas_calls": {}}, [bf16, fp8])
+    assert [f.check for f in short] == ["kernel_presence"]
+    assert audit({"pallas_calls": {}},
+                 [dataclasses.replace(bf16, pipeline="stream")])
+    # and the paper's plan traced end to end: clean, dgrad cells two-pass
+    cfg = get_config("tiny").replace(scan_layers=False,
+                                    linear_impl="pallas")
+    report = qlint.audit_train_graph(cfg, _tcfg(recipe="paper_fp4"),
+                                     label="paper", compile_hlo=False)
+    assert report.ok, report.violations()
+    dgrad = [c for c in report.cells
+             if c["cls"] == "ffn" and c["role"] == "dgrad"]
+    assert dgrad and all(c["mode_a"] == c["mode_b"] == "pass"
+                         and c["pipeline"] == "two_pass" for c in dgrad)
+
+
 def test_seeded_violation_fails_the_gate():
     """Trace a quantized-dgrad plan but audit against the paper's
     protected plan: the role-safety check must catch the quantize on the

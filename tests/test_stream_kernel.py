@@ -1,11 +1,12 @@
-"""Single-pass streaming pipeline vs the two-pass reference.
+"""Single-pass streaming pipeline vs the two-pass default.
 
-The contract (see ``fused_qmm``): for the SAME ``(bm, bn, bk)`` the stream
-and two_pass pipelines are **bit-identical** — same y, same telemetry
-stats — for every supported granularity pair, dtype, rounding mode and
-trans layout.  (Across *different* tilings only y's f32 accumulation order
-changes, which is true of the two-pass path too and deliberately not part
-of the contract.)
+The contract (see ``fused_qmm``): the stream and two_pass pipelines
+quantize every element to the same bits, so their telemetry stats are
+**bit-identical** for every supported granularity pair, dtype, rounding
+mode and trans layout.  The two-pass product is one XLA dot over the
+whole reduction axis, so ``y`` is bit-identical where the streaming
+kernel also takes a single K step (``bk == K``); with several K steps
+only y's f32 accumulation order differs.
 
 Everything runs in interpret mode on CPU (the fused_qmm default resolves
 interpret from the backend inside ops.py; here we pass interpret=True
@@ -56,23 +57,45 @@ def _assert_bits_equal(x, y, what=""):
         err_msg=f"bitwise mismatch: {what}")
 
 
+def _assert_same_sums(x, y, what=""):
+    """Equal up to the f32 accumulation order along K: a few f32 ulps of
+    the largest |y|, plus one output ulp where the output is bf16."""
+    assert x.dtype == y.dtype, (x.dtype, y.dtype, what)
+    x32, y32 = np.asarray(x, np.float32), np.asarray(y, np.float32)
+    scale = max(float(np.abs(y32).max()), 1.0)
+    rtol = 2.0 ** -7 if x.dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(x32, y32, rtol=rtol, atol=1e-5 * scale,
+                               err_msg=f"beyond accumulation order: {what}")
+
+
+def _assert_y(ys, yt, one_k_step, what=""):
+    """y bitwise where the streaming kernel took one K step, else equal
+    up to the accumulation order."""
+    if one_k_step:
+        _assert_bits_equal(ys, yt, what)
+    else:
+        _assert_same_sums(ys, yt, what)
+
+
 @pytest.mark.parametrize("tiles", TILINGS)
 @pytest.mark.parametrize("dtype,sr", [
     (jnp.float32, False), (jnp.float32, True), (jnp.bfloat16, False),
 ], ids=["f32_rtn", "f32_sr", "bf16_rtn"])
 def test_same_tiling_bit_exact(tiles, dtype, sr):
-    """Stream == two_pass bitwise at the same tiling: y AND the full
-    telemetry stats vectors, with the stats epilogue not perturbing y."""
+    """Stream vs two_pass: the full telemetry stats vectors bitwise, y
+    bitwise at one K step and to accumulation order otherwise, with the
+    stats epilogue not perturbing y."""
     a, b = _data((M, K), (K, N), dtype)
     kw = dict(a_mode="block", b_mode="tile", a_sr=sr, b_sr=sr,
               seed_a=SEED_A if sr else None, seed_b=SEED_B if sr else None)
+    one_k_step = tiles[2] == K
     (ys, (sa_s, sb_s)), (yt, (sa_t, sb_t)) = _run_both(
         a, b, tiles, collect_stats=True, **kw)
-    _assert_bits_equal(ys, yt, "y (stats on)")
+    _assert_y(ys, yt, one_k_step, "y (stats on)")
     _assert_bits_equal(sa_s, sa_t, "stats_a")
     _assert_bits_equal(sb_s, sb_t, "stats_b")
     ys_off, yt_off = _run_both(a, b, tiles, **kw)
-    _assert_bits_equal(ys_off, yt_off, "y (stats off)")
+    _assert_y(ys_off, yt_off, one_k_step, "y (stats off)")
     _assert_bits_equal(ys_off, ys, "y stats-on vs stats-off")
 
 
@@ -80,7 +103,7 @@ def test_bf16_sr_bit_exact():
     a, b = _data((M, K), (K, N), jnp.bfloat16, seed=3)
     ys, yt = _run_both(a, b, (128, 128, 128), a_mode="block", b_mode="tile",
                        a_sr=True, b_sr=True, seed_a=SEED_A, seed_b=SEED_B)
-    _assert_bits_equal(ys, yt, "bf16 SR")
+    _assert_same_sums(ys, yt, "bf16 SR")
 
 
 def test_pass_mode_dgrad_layout():
@@ -88,7 +111,7 @@ def test_pass_mode_dgrad_layout():
     g, w = _data((M, N), (K, N), jnp.bfloat16, seed=4)  # g @ w^T -> (M, K)
     ys, yt = _run_both(g, w, (128, 128, 128), a_mode="pass", b_mode="pass",
                        trans_b=True)
-    _assert_bits_equal(ys, yt, "pass/pass trans_b")
+    _assert_same_sums(ys, yt, "pass/pass trans_b")
 
 
 def test_wgrad_layout_fp8():
@@ -96,7 +119,7 @@ def test_wgrad_layout_fp8():
     x, g = _data((K, M), (K, N), seed=5)  # x^T @ g with trans_a
     ys, yt = _run_both(x, g, (128, 128, 128), a_mode="block", b_mode="block",
                        a_fmt="fp8_e4m3", b_fmt="fp8_e5m2", trans_a=True)
-    _assert_bits_equal(ys, yt, "block/block fp8 trans_a")
+    _assert_same_sums(ys, yt, "block/block fp8 trans_a")
 
 
 def test_token_granularity_falls_back_to_two_pass():
@@ -135,9 +158,10 @@ def test_operand_cache_bit_exact(monkeypatch):
 @pytest.mark.parametrize("recipe,name", [(MM_FFN_PAPER, "ffn_paper"),
                                          (MM_FP8, "fp8")])
 def test_qlinear_stream_vs_two_pass(recipe, name):
-    """Through the training entry points: ``pallas_qmatmul`` (stream) and
-    ``pallas_qmatmul_two_pass`` agree bitwise on fwd AND the vjp
-    (dgrad + wgrad).  MM_FP8 exercises the token-granularity fallback."""
+    """Through the training entry points: ``pallas_qmatmul`` under
+    ``use_pipeline("stream")`` and ``pallas_qmatmul_two_pass`` agree
+    bitwise on fwd AND the vjp (dgrad + wgrad): every dim is 128, one K
+    step.  MM_FP8 exercises the token-granularity fallback."""
     key = jnp.zeros((2,), jnp.uint32)
     x, w = _data((128, 128), (128, 128), seed=8)
     c = jax.random.normal(jax.random.PRNGKey(9), (128, 128), jnp.float32)
@@ -147,7 +171,8 @@ def test_qlinear_stream_vs_two_pass(recipe, name):
         dx, dw = vjp(c)
         return y, dx, dw
 
-    ys, dxs, dws = run(pallas_qmatmul)
+    with use_pipeline("stream"):
+        ys, dxs, dws = run(pallas_qmatmul)
     yt, dxt, dwt = run(pallas_qmatmul_two_pass)
     _assert_bits_equal(ys, yt, f"{name} fwd")
     _assert_bits_equal(dxs, dxt, f"{name} dgrad")
@@ -155,13 +180,13 @@ def test_qlinear_stream_vs_two_pass(recipe, name):
 
 
 def test_use_pipeline_nesting():
-    assert default_pipeline() == "stream"
-    with use_pipeline("two_pass"):
-        assert default_pipeline() == "two_pass"
-        with use_pipeline("stream"):
-            assert default_pipeline() == "stream"
-        assert default_pipeline() == "two_pass"
-    assert default_pipeline() == "stream"
+    assert default_pipeline() == "two_pass"
+    with use_pipeline("stream"):
+        assert default_pipeline() == "stream"
+        with use_pipeline("two_pass"):
+            assert default_pipeline() == "two_pass"
+        assert default_pipeline() == "stream"
+    assert default_pipeline() == "two_pass"
     with pytest.raises(AssertionError):
         with use_pipeline("bogus"):
             pass

@@ -79,8 +79,23 @@ def _seed():
 QMM_CASES = {
     # the paper's FFN forward: per-block FP4 activations, per-tile weights
     "stream_fwd_block_tile": (
+        dict(a_mode="block", b_mode="tile", pipeline="stream"),
+        (_bf16(TOKENS, D), _bf16(D, FF))),
+    # the same role on the default pipeline: two quantize passes, XLA's dot
+    "two_pass_fwd_block_tile": (
         dict(a_mode="block", b_mode="tile"), (_bf16(TOKENS, D),
                                               _bf16(D, FF))),
+    # ... and at GPT-2-774M's widths (d_model 1280, d_ff 5120)
+    "two_pass_fwd_block_tile_774m": (
+        dict(a_mode="block", b_mode="tile"), (_bf16(TOKENS, 1280),
+                                              _bf16(1280, 5120))),
+    "attn_token_token_fp8_774m": (
+        dict(a_mode="token", b_mode="token", a_fmt="fp8_e4m3",
+             b_fmt="fp8_e4m3"), (_bf16(TOKENS, 1280), _bf16(1280, 3840))),
+    # the paper's bf16 FFN dgrad: XLA's dot alone, no kernel
+    "dgrad_pass_pass_trans_b": (
+        dict(a_mode="pass", b_mode="pass", trans_b=True),
+        (_bf16(TOKENS, FF), _bf16(D, FF))),
     # attention linears under paper_fp4: per-token FP8 (two-pass)
     "attn_token_token_fp8": (
         dict(a_mode="token", b_mode="token", a_fmt="fp8_e4m3",
@@ -110,7 +125,10 @@ def test_fused_qmm_compiles_for_v5e(on_chip, case):
         return FM.fused_qmm(a, b, interpret=False, **kw, **sk)
 
     compiled = jax.jit(f).lower(*on_chip(shapes)).compile()
-    assert KERNEL in compiled.as_text()
+    # Only a bf16 x bf16 role off the streaming pipeline runs no kernel.
+    quantizes = kw["a_mode"] != "pass" or kw["b_mode"] != "pass"
+    expect_kernel = quantizes or kw.get("pipeline") == "stream"
+    assert (KERNEL in compiled.as_text()) == expect_kernel
 
 
 def test_flash_attention_fwd_compiles_for_v5e(on_chip):
