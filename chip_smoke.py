@@ -297,8 +297,10 @@ def main(argv=None) -> int:
             GPT2_125M.replace(linear_impl="pallas"))
         serve_phase(model, params, pipe)
     else:
-        # Mosaic kernels cannot be partitioned by GSPMD ("wrap the call in
-        # a shard_map"), so the mesh trainer runs the QDQ linears.
+        # The fused kernels run under a mesh through the sharded quantize
+        # pass (kernels/ops.py), measured by the benchmark's four-chip
+        # cell; this phase keeps the QDQ linears, the simulation it
+        # compares the mesh, one-device and fp8-compressed runs on.
         mesh_phase(GPT2_125M.replace(linear_impl="qdq"), devices)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,

@@ -239,6 +239,7 @@ def _dot_fused(a: jnp.ndarray, b: jnp.ndarray,
         # Deferred import: kernels.ops pulls in models.attention (cycle via
         # this module at import time).
         from repro.kernels.ops import pallas_qmm
+        from repro.nn.layers import get_sharding_context
         with _role_scope(role):
             return pallas_qmm(a, b, spec_a, spec_b,
                               mode_a=mode_a, mode_b=mode_b,
@@ -246,7 +247,8 @@ def _dot_fused(a: jnp.ndarray, b: jnp.ndarray,
                               key_data=key_data, salt=salt,
                               pipeline=pipeline,
                               collect_stats=collect_stats, role=role,
-                              cell=cell)
+                              cell=cell, axes_a=axes_a, axes_b=axes_b,
+                              rules=get_sharding_context())
     reasons = tuple(
         f"{operand}: {why}"
         for operand, spec in (("lhs", spec_a), ("rhs", spec_b))

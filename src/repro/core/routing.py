@@ -67,6 +67,11 @@ class RouteEvent:
     operand (spec says ``:sr`` AND key material reached the call) — the
     check "SR appears exactly where specs say so" compares these against
     the plan, catching dropped-key bugs as well as spec drift.
+
+    ``sharded``: a ``pallas`` role's quantize passes ran under
+    ``shard_map`` over a mesh of more than one device (``kernels.ops``);
+    ``cross_amax``: one of its amax groups spanned chips and took a
+    cross-chip ``pmax`` before quantizing.
     """
     layer: Optional[str]
     cls: Optional[str]
@@ -80,12 +85,15 @@ class RouteEvent:
     sr_a: bool = False
     sr_b: bool = False
     reasons: Tuple[str, ...] = ()
+    sharded: bool = False
+    cross_amax: bool = False
 
     def cell(self) -> Tuple:
         """Dedupe identity: trace-order independent, retrace stable."""
         return (self.layer, self.cls, self.role, self.route,
                 self.spec_a, self.spec_b, self.mode_a, self.mode_b,
-                self.pipeline, self.sr_a, self.sr_b, self.reasons)
+                self.pipeline, self.sr_a, self.sr_b, self.reasons,
+                self.sharded, self.cross_amax)
 
     def to_dict(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -202,8 +210,8 @@ def record(role: str, route: str, spec_a, spec_b, *,
            pipeline: Optional[str] = None,
            sr_a: bool = False, sr_b: bool = False,
            reasons: Tuple[str, ...] = (),
-           cell: Optional[Tuple[Optional[str], Optional[str]]] = None
-           ) -> None:
+           cell: Optional[Tuple[Optional[str], Optional[str]]] = None,
+           sharded: bool = False, cross_amax: bool = False) -> None:
     """Append a routing decision (no-op unless a log is installed).
 
     ``cell`` overrides the ambient (layer, class) attribution — required
@@ -217,4 +225,5 @@ def record(role: str, route: str, spec_a, spec_b, *,
     log.add(RouteEvent(
         layer=layer, cls=cls, role=role, route=route,
         spec_a=str(spec_a), spec_b=str(spec_b), mode_a=mode_a, mode_b=mode_b,
-        pipeline=pipeline, sr_a=sr_a, sr_b=sr_b, reasons=tuple(reasons)))
+        pipeline=pipeline, sr_a=sr_a, sr_b=sr_b, reasons=tuple(reasons),
+        sharded=sharded, cross_amax=cross_amax))

@@ -92,9 +92,9 @@ from repro.core.formats import FORMATS
 from repro.kernels.rounding import (group_scale, hash_uniform, round_to_grid,
                                     snap_to_dtype, uniform_from_bits)
 
-__all__ = ["fp4_matmul", "fused_qmm", "quantize_panels", "interpret_mode",
-           "finalize_quant_stats", "QUANT_MODES", "STATS_WIDTH",
-           "PIPELINES", "default_pipeline", "use_pipeline",
+__all__ = ["fp4_matmul", "fused_qmm", "quantize_panels", "quantize_for_dot",
+           "interpret_mode", "finalize_quant_stats", "QUANT_MODES",
+           "STATS_WIDTH", "PIPELINES", "default_pipeline", "use_pipeline",
            "stream_supported", "resolve_pipeline"]
 
 QUANT_MODES = ("pass", "block", "tile", "token", "tensor")
@@ -298,7 +298,7 @@ def _stats_fold(sacc_ref, lane):
 
 def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
                   grid_kind, bq, bkq, nk, block, m_real, k_real,
-                  collect_stats):
+                  collect_stats, ext_amax):
     """QDQ one (bq, bkq) quant-orientation panel tile.
 
     Quant orientation = (non-reduction rows, reduction cols): (M, K) for
@@ -312,6 +312,9 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
     accumulates per-row amax in scratch; 'tensor' = grid (2, panels,
     ktiles), sweep 0 accumulates one global amax (the scale group spans the
     whole operand, so amax must complete before any element quantizes).
+    ``ext_amax``: a ``token``/``tensor`` pass whose amax arrives as an
+    input (per quant-orientation row, or one SMEM scalar) runs the single
+    sweep 'one' — the sharded pass, whose groups span chips, takes it so.
     """
     it = iter(refs)
     seed_ref = next(it) if sr else None
@@ -319,6 +322,7 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
     # would be strength-reduced to reciprocal-multiply inside the kernel
     # (1 ulp off the QDQ reference's true division, and non-idempotent).
     qmax_ref = next(it)
+    amax_in_ref = next(it) if ext_amax else None
     x_ref, o_ref = next(it), next(it)
     stats_ref = next(it) if collect_stats else None
     amax_ref = next(it) if grid_kind in ("token", "tensor") else None
@@ -431,16 +435,21 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
                         _stats_slab_flush(sacc_ref, p * (bq // block) + i,
                                           lane, st)
         else:  # token / tensor: scale broadcast from the amax scratch
+            # (or the amax input)
             # tensor: one scalar scale (Mosaic cannot broadcast a (1, 1)
             # vector over sublanes and lanes at once).
-            amax = (amax_ref[...] if grid_kind == "token"
-                    else amax_ref[0, 0])
+            if ext_amax:
+                amax = (amax_in_ref[...].astype(jnp.float32)
+                        if mode == "token" else amax_in_ref[0])
+            else:
+                amax = (amax_ref[...] if grid_kind == "token"
+                        else amax_ref[0, 0])
             scale = group_scale(amax, fmt, pow2, qm)
             sc = scale.astype(in_dt)
             qt = round_to_grid(xt / sc, fmt, noise) * sc
             o_ref[...] = qt.T if emit_trans else qt
             if collect_stats:
-                gvalid = rows_valid if grid_kind == "token" else True
+                gvalid = rows_valid if mode == "token" else True
                 st = _stats_init()
                 _stats_accum(st, xt, qt, scale, gvalid, fmt)
                 st["cnt"] = (jnp.sum(rows_valid.astype(jnp.float32))
@@ -465,7 +474,8 @@ def _quant_kernel(*refs, mode, fmt, pow2, sr, trans, emit_trans, use_hw_rng,
 def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
                       sr: bool, seed: Optional[jnp.ndarray], trans: bool,
                       emit_trans: bool, block: int, m_real: int, k_real: int,
-                      collect_stats: bool, interpret: bool
+                      collect_stats: bool, interpret: bool,
+                      amax: Optional[jnp.ndarray] = None
                       ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Run the quantize pass over one padded stored operand.
 
@@ -473,7 +483,10 @@ def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
     stored layout when ``trans`` — QDQ'd once, and written out as
     (rows, reduction), or (reduction, rows) when ``emit_trans`` (the RHS
     case, so phase 2 reads (K, N) plain).  Also returns the raw stats
-    vector when ``collect_stats``.
+    vector when ``collect_stats``.  ``amax`` (``token``/``tensor`` only):
+    the groups' amax, given rather than swept, so one sweep quantizes:
+    (rows, 1) in ``t``'s dtype for ``token`` (a max of its magnitudes is
+    exact in it), one f32 in SMEM, (1,), for ``tensor``.
     """
     if trans:
         k_eff, m_eff = t.shape
@@ -481,8 +494,11 @@ def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
         m_eff, k_eff = t.shape
     bq, bkq = _pick_tile(m_eff, block), _pick_tile(k_eff, block)
     np_, nk = m_eff // bq, k_eff // bkq
-    grid_kind = {"block": "one", "tile": "one",
-                 "token": "token", "tensor": "tensor"}[mode]
+    ext_amax = amax is not None
+    assert not ext_amax or mode in ("token", "tensor"), mode
+    grid_kind = ("one" if ext_amax else
+                 {"block": "one", "tile": "one",
+                  "token": "token", "tensor": "tensor"}[mode])
 
     if grid_kind == "one":
         grid = (np_, nk)
@@ -510,6 +526,13 @@ def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     operands.append(jax.lax.optimization_barrier(
         jnp.full((1,), fmt.max_value, jnp.float32)))
+    if ext_amax and mode == "token":
+        in_specs.append(pl.BlockSpec((bq, 1),
+                                     lambda *ids: (gids(*ids)[0], 0)))
+        operands.append(amax.reshape(m_eff, 1))
+    elif ext_amax:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(amax.reshape(1).astype(jnp.float32))
     in_specs.append(pl.BlockSpec((bkq, bq) if trans else (bq, bkq), xmap))
     operands.append(t)
 
@@ -539,7 +562,7 @@ def _quantize_operand(t: jnp.ndarray, *, mode: str, fmt, pow2: bool,
         _quant_kernel, mode=mode, fmt=fmt, pow2=pow2, sr=sr, trans=trans,
         emit_trans=emit_trans, use_hw_rng=not interpret, grid_kind=grid_kind,
         bq=bq, bkq=bkq, nk=nk, block=block, m_real=m_real, k_real=k_real,
-        collect_stats=collect_stats)
+        collect_stats=collect_stats, ext_amax=ext_amax)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -587,6 +610,31 @@ def quantize_panels(t: jnp.ndarray, *, mode: str = "block",
         trans=trans, emit_trans=False, block=block, m_real=m_real,
         k_real=k_real, collect_stats=collect_stats, interpret=interpret)
     return (q, stats) if collect_stats else q
+
+
+def quantize_for_dot(t: jnp.ndarray, *, lhs: bool, mode: str,
+                     fmt_name: str, pow2: bool, sr: bool,
+                     seed: Optional[jnp.ndarray], trans: bool, block: int,
+                     rows_real: int, k_real: int, collect_stats: bool,
+                     interpret: bool, amax: Optional[jnp.ndarray] = None
+                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """Phase 1 for one stored operand of ``A' @ B'`` (``trans`` is its
+    ``trans_a``/``trans_b``), emitted in the layout the product reads
+    plain: the LHS as (M, K), the RHS as (K, N).
+
+    The LHS quant orientation (M, K) is its effective orientation.  The
+    RHS quant orientation is (N, K) — groups reduce over K, which is axis
+    0 of the effective (K, N) — so its pass reads the stored array
+    transposed iff NOT ``trans`` and emits (K, N) back.  ``rows_real``/
+    ``k_real``: the unpadded quant-orientation dims (stats masking);
+    ``amax``: see ``_quantize_operand``.
+    """
+    fmt = FORMATS[fmt_name]
+    return _quantize_operand(
+        t, mode=mode, fmt=fmt, pow2=pow2, sr=sr and not fmt.passthrough,
+        seed=seed, trans=trans if lhs else not trans, emit_trans=not lhs,
+        block=block, m_real=rows_real, k_real=k_real,
+        collect_stats=collect_stats, interpret=interpret, amax=amax)
 
 
 # ---------------------------------------------------------------------------
@@ -965,22 +1013,15 @@ def _fused_qmm(a: jnp.ndarray, b: jnp.ndarray, *,
     stats_a = stats_b = None
     mm_trans_a, mm_trans_b = trans_a, trans_b
     if a_mode != "pass":
-        # LHS quant orientation (M, K) == effective orientation; the
-        # product reads it plain.
-        a, stats_a = _quantize_operand(
-            a, mode=a_mode, fmt=FORMATS[a_fmt], pow2=a_pow2,
-            sr=a_sr and not FORMATS[a_fmt].passthrough, seed=seed_a,
-            trans=trans_a, emit_trans=False, block=block, m_real=mr,
+        a, stats_a = quantize_for_dot(
+            a, lhs=True, mode=a_mode, fmt_name=a_fmt, pow2=a_pow2, sr=a_sr,
+            seed=seed_a, trans=trans_a, block=block, rows_real=mr,
             k_real=kr, collect_stats=collect_stats, interpret=interpret)
         mm_trans_a = False
     if b_mode != "pass":
-        # RHS quant orientation is (N, K) — groups reduce over K, which is
-        # axis 0 of the effective (K, N) — so the pass reads the stored
-        # array transposed iff NOT trans_b, and emits (K, N) back.
-        b, stats_b = _quantize_operand(
-            b, mode=b_mode, fmt=FORMATS[b_fmt], pow2=b_pow2,
-            sr=b_sr and not FORMATS[b_fmt].passthrough, seed=seed_b,
-            trans=not trans_b, emit_trans=True, block=block, m_real=nr,
+        b, stats_b = quantize_for_dot(
+            b, lhs=False, mode=b_mode, fmt_name=b_fmt, pow2=b_pow2, sr=b_sr,
+            seed=seed_b, trans=trans_b, block=block, rows_real=nr,
             k_real=kr, collect_stats=collect_stats, interpret=interpret)
         mm_trans_b = False
 

@@ -22,8 +22,11 @@ import pytest
 from repro.configs.base import TrainConfig
 from repro.configs.gpt2_125m import CONFIG as GPT2_125M
 from repro.core.recipe import RECIPES, as_plan
+from repro.distributed.mesh import make_mesh
+from repro.distributed.sharding import default_rules
 from repro.models import build_model
-from repro.train.train_step import make_optimizer, make_train_step
+from repro.train.train_step import (make_optimizer, make_train_step,
+                                    train_step_shardings)
 
 FM = importlib.import_module("repro.kernels.fp4_matmul")
 FA = importlib.import_module("repro.kernels.flash_attention")
@@ -140,28 +143,61 @@ def test_flash_attention_fwd_compiles_for_v5e(on_chip):
     assert KERNEL in compiled.as_text()
 
 
+def _step_args(model, tcfg):
+    """Abstract arguments of the 6-arg train step."""
+    params = model.abstract_params(jnp.float32)
+    opt = jax.eval_shape(make_optimizer(model, tcfg).init, params)
+    tokens = jax.ShapeDtypeStruct((tcfg.global_batch, tcfg.seq_len),
+                                  jnp.int32)
+    return (params, opt, jax.ShapeDtypeStruct((), jnp.float32),
+            {"tokens": tokens, "targets": tokens},
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+
+
 def test_paper_fp4_train_step_compiles_for_v5e(on_chip, monkeypatch):
     """One whole fused-kernel paper_fp4 train step at GPT-2-125M widths,
-    two layers deep; it must fit one 16 GB chip."""
+    two layers deep; it must fit one 16 GB chip, and its Mosaic calls are
+    the one-device path's: 36 at these widths."""
     # Kernels decide interpret mode from the backend, which is the CPU
     # here: steer them to the chip's compiler.
     monkeypatch.setattr(FM, "interpret_mode", lambda: False)
     cfg = GPT2_125M.replace(n_layers=2, linear_impl="pallas")
     model = build_model(cfg)
-    seq, batch = 1024, TOKENS // 1024
     tcfg = TrainConfig(recipe="paper_fp4", total_steps=20,
-                       global_batch=batch, seq_len=seq)
-    params = model.abstract_params(jnp.float32)
-    opt = jax.eval_shape(make_optimizer(model, tcfg).init, params)
-    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-    args = (params, opt, jax.ShapeDtypeStruct((), jnp.float32),
-            {"tokens": tokens, "targets": tokens},
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.float32))
+                       global_batch=TOKENS // 1024, seq_len=1024)
     step = make_train_step(model, tcfg,
                            as_plan(RECIPES["paper_fp4"], cfg.n_layers),
                            donate=False)
-    compiled = step.lower(*on_chip(args)).compile()
+    compiled = step.lower(*on_chip(_step_args(model, tcfg))).compile()
+    assert compiled.as_text().count(KERNEL) == 36
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+
+
+def test_paper_fp4_fsdp_step_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The same step on a described four-chip host, mesh (4, 1) with FSDP
+    and 8 rows a chip: GSPMD cannot partition a Mosaic kernel, so each
+    quantize pass runs under ``shard_map`` (``kernels/ops.py``); the
+    kernels stay in, and the step fits each chip's 16 GB."""
+    monkeypatch.setattr(FM, "interpret_mode", lambda: False)
+    cfg = GPT2_125M.replace(n_layers=2, linear_impl="pallas")
+    model = build_model(cfg)
+    tcfg = TrainConfig(recipe="paper_fp4", total_steps=20,
+                       global_batch=4 * TOKENS // 1024, seq_len=1024,
+                       mesh_shape=(4, 1), fsdp=True)
+    rules = default_rules(make_mesh((4, 1), ("data", "model"),
+                                    devices=topo.devices), cfg, fsdp=True)
+    shardings = list(train_step_shardings(model, tcfg, rules)[0])
+    shardings[3] = {"tokens": shardings[3], "targets": shardings[3]}
+    args = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), _step_args(model, tcfg),
+        tuple(shardings))
+    step = make_train_step(model, tcfg,
+                           as_plan(RECIPES["paper_fp4"], cfg.n_layers),
+                           donate=False, rules=rules)
+    compiled = step.lower(*args).compile()
     assert KERNEL in compiled.as_text()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
