@@ -136,10 +136,11 @@ def _bench_telemetry_epilogue(x, w, recipe, tag: str) -> None:
 
 
 def _bench_flash_attention() -> None:
-    """Pallas flash-attention forward kernel (interpret mode on CPU) vs the
-    chunked-jnp path at the same shape — closes the benchmark coverage gap:
-    the matmul kernels were regression-guarded, the attention kernel was
-    not.  256-seq keeps interpret-mode runtime sane (grid 8 * 2 * 2)."""
+    """The splash flash-attention forward kernel (interpret mode on CPU)
+    vs the chunked-jnp path at the same shape — closes the benchmark
+    coverage gap: the matmul kernels were regression-guarded, the
+    attention kernel was not.  256-seq keeps interpret-mode runtime sane
+    (one 256 x 256 tile per batch row and head)."""
     from repro.kernels import flash_attention
     b, s, h, d = 2, 256, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -147,14 +148,14 @@ def _bench_flash_attention() -> None:
     k = jax.random.normal(ks[1], (b, s, h, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, s, h, d), jnp.float32)
     pos = jnp.arange(s, dtype=jnp.int32)
-    f_flash = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, chunk=128))
+    f_flash = jax.jit(lambda q, k, v: flash_attention(q, k, v,
+                                                      causal=True))
     f_chunk = jax.jit(lambda q, k, v: chunked_attention(
         q, k, v, pos, pos, causal=True, chunk=128))
     t_f = timeit(f_flash, q, k, v, n=10)
     t_c = timeit(f_chunk, q, k, v, n=10)
     emit("kernel/flash_attention_fwd_256", t_f,
-         f"impl=pallas_interpret;bq=128;bk=128;rel_chunked={t_f / t_c:.2f}")
+         f"impl=splash_interpret;block=256;rel_chunked={t_f / t_c:.2f}")
     emit("kernel/attention_chunked_256", t_c, "impl=chunked_jnp;chunk=128")
 
 

@@ -222,7 +222,9 @@ def graph_census(closed_jaxpr, compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
     count; role ``"-"`` for quantize ops outside any matmul role, e.g. the
     KV-cache codec), and ``f32_kernel_operands`` — pallas_call equations
     with a floating operand wider than ``compute_dtype`` (the "no f32
-    upcast into fp4-routed matmuls" check).
+    upcast into fp4-routed matmuls" check), the splash flash-attention
+    kernels excepted: their backward reads the float32 softmax
+    statistics.
     """
     pallas = Counter()
     qdq = Counter()
@@ -235,7 +237,8 @@ def graph_census(closed_jaxpr, compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
         role = roles[-1] if roles else None
         if eqn.primitive.name == "pallas_call":
             pallas[role or "-"] += 1
-            for var in eqn.invars:
+            splash = str(eqn.params.get("name", "")).startswith("splash_")
+            for var in (() if splash else eqn.invars):
                 aval = getattr(var, "aval", None)
                 dt = getattr(aval, "dtype", None)
                 # Matrix operands only: scalar/vector kernel parameters
@@ -316,6 +319,8 @@ def audit_cells(cells: Sequence[routing.RouteEvent], plan: PrecisionPlan,
     head_seen = False
 
     for ev in cells:
+        if ev.role == "sdpa":
+            continue  # the attention core: no matmul role of the plan
         where = f"{ev.layer or 'head'}/{ev.cls or '?'}/{ev.role}"
         if ev.cls is None:
             findings.append(Finding(

@@ -67,7 +67,10 @@ class ModelConfig:
     n_patches: int = 1601        # vlm frontend stub
     # numerics / compile strategy
     dtype: str = "bfloat16"
-    attention_impl: str = "chunked"   # chunked | pallas (TPU flash kernel)
+    # selects nothing: attention takes the flash kernels on a TPU where it
+    # can and the chunked path elsewhere (models.attention); "chunked" stays
+    # accepted because saved configurations pin it
+    attention_impl: str = "chunked"
     linear_impl: str = "qdq"          # qdq (unfused sim) | pallas (fused
     #                                   quantize+matmul kernel, fwd+dgrad+wgrad)
     attention_chunk: int = 1024

@@ -5,10 +5,11 @@
   rounding          shared bit-exact integer RTN / stochastic-rounding
                     codec + counter-hash noise (single source of truth)
   quantize          standalone per-tile quantizer
-  flash_attention   causal online-softmax attention fwd (§3.1 protection)
 
 Each kernel ships with ops.py (jit'd wrapper + interpret fallback on CPU)
 and ref.py (pure-jnp oracle used by the allclose test sweeps).
+``ops.flash_attention`` wraps JAX's own Pallas TPU flash kernels (splash
+attention, forward and backward) for self-attention (§3.1 protection).
 
 ``fp4_matmul.fused_qmm`` / ``ops.pallas_qmm`` form the role-parameterized
 quantized-matmul family backing the training path's fwd, dgrad and wgrad

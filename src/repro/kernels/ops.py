@@ -1,5 +1,5 @@
-"""jit'd public wrappers for the Pallas kernels: padding, GQA handling,
-custom_vjp glue, the mesh split of the quantize pass, and interpret-mode
+"""jit'd public wrappers for the Pallas kernels: padding, the mesh split
+of the quantize pass, the splash flash-attention call, and interpret-mode
 fallback for CPU.
 
 Off a TPU every entry point runs with ``interpret=True`` (decided by
@@ -12,7 +12,8 @@ partition a Mosaic kernel, so ``pallas_qmm`` runs each operand's quantize
 pass under ``shard_map`` over the whole mesh (``shard_plan`` says how each
 operand splits) and leaves the product to XLA's dot, which GSPMD
 partitions.  Without a mesh, or on one device, the call is the one-device
-pipeline unchanged.
+pipeline unchanged.  ``flash_attention`` likewise runs under
+``shard_map`` over such a mesh.
 """
 from __future__ import annotations
 
@@ -22,17 +23,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash
 from jax.sharding import PartitionSpec as P
 
 from repro.core import routing
 from repro.core.quantize import QuantSpec
 from repro.kernels import fp4_matmul as _mm
 from repro.kernels import quantize as _q
-from repro.kernels import flash_attention as _fa
-from repro.models.attention import chunked_attention
 
 __all__ = ["fp4_matmul", "pallas_qmm", "quantize_blockwise",
-           "flash_attention"]
+           "flash_attention", "flash_route"]
 
 
 def _pad2d(x, block):
@@ -281,50 +281,109 @@ def quantize_blockwise(x: jnp.ndarray, fmt_name: str = "fp4_e2m1",
     return y[:m, :n]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, chunk, interpret):
-    """(B, S, H, D) attention; Pallas fwd, chunked-jnp bwd."""
-    b, sq, h, d = q.shape
+def _flash_block(seq: int) -> int:
+    """The splash kernels' tile along both sequence axes, for the forward,
+    ``dq`` and ``dkv`` kernels alike: the largest of 512, 256 and 128 that
+    divides the sequence."""
+    return next(b for b in (512, 256, 128) if seq % b == 0)
+
+
+def _splash_call(q, k, v, *, causal: bool, interpret: bool):
+    """Pre-scaled (B, S, H, D) queries against (B, S, KVH, D) keys and
+    values on one device: the splash kernels, vmapped over batch.  One
+    KV head takes the MQA kernel, more the MHA kernel's own head grouping,
+    so K and V are never repeated per query head."""
+    s, h = q.shape[1], q.shape[2]
     kvh = k.shape[2]
-    rep = h // kvh
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
-    bq = min(128, sq)
-    bk = min(128, kf.shape[1])
-    o = _fa.flash_attention_fwd(qf, kf, vf, causal=causal, bq=bq, bk=bk,
-                                interpret=interpret)
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    blk = _flash_block(s)
+    mask = (_splash.CausalMask if causal else _splash.FullMask)((s, s))
+    sizes = _splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk)
+    make = (_splash.make_splash_mqa_single_device if kvh == 1
+            else _splash.make_splash_mha_single_device)
+    kernel = make(_splash.MultiHeadMask([mask] * h), block_sizes=sizes,
+                  interpret=interpret)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    if kvh == 1:
+        kt, vt = kt[:, 0], vt[:, 0]
+    return jax.vmap(kernel)(qt, kt, vt).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, causal, chunk, interpret):
-    return _flash(q, k, v, causal, chunk, interpret), (q, k, v)
+# Ambient default matmul precisions under which the splash kernels' bf16
+# dots compile: Mosaic refuses HIGH and float32 contract precisions.
+_FLASH_PRECISIONS = (None, "default", "bfloat16")
 
 
-def _flash_bwd(causal, chunk, interpret, res, g):
-    q, k, v = res
+def flash_route(q_shape, k_shape, rules=None):
+    """Whether the splash kernels can run self-attention here, and how
+    they split over the mesh: ``(reasons, specs)``.
 
-    def ref_fn(q, k, v):
-        sq = q.shape[1]
-        pos = jnp.arange(sq, dtype=jnp.int32)
-        kpos = jnp.arange(k.shape[1], dtype=jnp.int32)
-        return chunked_attention(q, k, v, pos, kpos, causal=causal,
-                                 chunk=chunk)
+    ``reasons`` names each condition that keeps the call off the kernels
+    (empty: they run): ``not_tpu``; ``precision``, an ambient default
+    matmul precision above bfloat16; ``seq_len``, q and k lengths that
+    differ or are no multiple of 128.  Under ``rules`` whose mesh has more
+    than one device, also ``seq_sharded``, a sequence axis split over the
+    mesh; ``kv_heads_split``, KV heads split unlike the query heads; and
+    ``mesh_axis_unused``, a mesh axis that neither q's batch nor its heads
+    take, along which ``shard_map`` would repeat the whole call (the
+    vmapped per-data-shard body of the fp8-compressed data-parallel step,
+    whose rules leave the batch unmapped).  ``specs``: the ``shard_map``
+    specs of q and of k/v there, batch over its mesh axes and heads over
+    theirs (``models.attention`` hints the same logical axes); ``None`` on
+    one device or when a reason holds."""
+    reasons = []
+    if _mm.interpret_mode():
+        reasons.append("not_tpu")
+    if jax.config.jax_default_matmul_precision not in _FLASH_PRECISIONS:
+        reasons.append("precision")
+    if q_shape[1] != k_shape[1] or q_shape[1] % 128:
+        reasons.append("seq_len")
+    if rules is None or rules.mesh.size == 1:
+        return tuple(reasons), None
 
-    _, vjp = jax.vjp(ref_fn, q, k, v)
-    return vjp(g)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
+    def spec(axes, shape):
+        return P(*(tuple(rules.activation_sharding(axes, shape).spec)
+                   + (None,) * 4)[:4])
+    q_spec = spec(("batch", "seq_q", "heads", None), q_shape)
+    kv_spec = spec(("batch", "seq", "kv_heads", None), k_shape)
+    if q_spec[1] is not None or kv_spec[1] is not None:
+        reasons.append("seq_sharded")
+    if k_shape[2] > 1 and kv_spec[2] != q_spec[2]:
+        reasons.append("kv_heads_split")
+    used = {a for entry in (q_spec[0], q_spec[2]) if entry is not None
+            for a in ((entry,) if isinstance(entry, str) else entry)}
+    if any(n > 1 and a not in used for a, n in rules.mesh.shape.items()):
+        reasons.append("mesh_axis_unused")
+    return tuple(reasons), (None if reasons else (rules.mesh, q_spec,
+                                                 kv_spec))
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, chunk: int = 1024,
+                    causal: bool = True, specs=None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Differentiable flash attention: Pallas forward (TPU target),
-    chunked-jnp backward.  q/k/v: (B, S, H|KVH, D)."""
+    """softmax(q kᵀ / √D) v for self-attention, forward and backward on
+    the splash flash kernels of JAX's Pallas TPU ops: scores and
+    probabilities stay in VMEM, and tiles a causal mask hides are skipped.
+
+    q: (B, S, H, D); k, v: (B, S, KVH, D), KVH dividing H; S a multiple of
+    128.  q is scaled by 1/√D in its own dtype first, as
+    ``models.attention.chunked_attention`` does.  The kernels' dots read
+    q, k, v, dO and the backward's P and dS in the operands' dtype and
+    accumulate in float32, as do the softmax statistics; the forward's P·V
+    multiplies P by V upcast to float32 (the chunked path casts P to V's
+    dtype).  ``specs`` (``(mesh, q_spec, kv_spec)`` from ``flash_route``)
+    runs the call inside ``shard_map`` over that mesh: GSPMD cannot
+    partition a Mosaic call.
+    """
     interpret = _mm.interpret_mode() if interpret is None else interpret
-    return _flash(q, k, v, causal, chunk, interpret)
+    q = q * jnp.asarray(1.0 / math.sqrt(q.shape[-1]), q.dtype)
+    call = functools.partial(_splash_call, causal=causal,
+                             interpret=interpret)
+    if specs is None:
+        return call(q, k, v)
+    mesh, q_spec, kv_spec = specs
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False)(q, k, v)

@@ -4,9 +4,12 @@ Per the paper (§3.1 + App. B): the QKV and output projections are
 "attention-protected" linears (FP8 under the paper recipe; the
 ``MatmulRecipe`` argument is the layer's attn cell of the active
 ``PrecisionPlan``), while the attention math itself (softmax(QK^T)V) always
-runs in the compute dtype via a FlashAttention-equivalent — here a chunked
-online-softmax over KV blocks (O(S * chunk) memory), optionally the Pallas
-kernel on TPU.
+runs in the compute dtype via FlashAttention: on a TPU, self-attention with
+no cache and no window runs the splash flash kernels forward and backward
+(``kernels.ops.flash_attention``); everything else — the CPU, decode and
+prefill with a cache, sliding windows, cross-attention — runs a chunked
+online-softmax over KV blocks (O(S * chunk) memory).  Each call's route is
+recorded in the routing census as role ``sdpa``.
 
 Cache variants:
   * full ring-less cache  (decode with full attention)
@@ -23,8 +26,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import routing
 from repro.core.recipe import MatmulRecipe
-from repro.nn.layers import linear, rope, shard_hint
+from repro.kernels import ops as _kops
+from repro.nn.layers import get_sharding_context, linear, rope, shard_hint
 from repro.nn.params import ParamSpec
 from repro.telemetry.profiler import graph_span
 
@@ -192,6 +197,31 @@ def chunked_attention(
 # Full attention sublayer (projections + SDPA [+ cache update])
 # ---------------------------------------------------------------------------
 
+def _flash_route(q, k, rules, *, cache, window: int,
+                default_positions: bool):
+    """``(reasons, specs)``: why self-attention cannot take the flash
+    kernels here (empty: it can), and their ``shard_map`` specs under a
+    mesh.  The model's conditions are no cache, no window and positions
+    that are the plain ``arange``; the kernel's are
+    ``kernels.ops.flash_route``'s."""
+    reasons = tuple(r for r, hit in (("cache", cache is not None),
+                                     ("window", bool(window)),
+                                     ("positions", not default_positions))
+                    if hit)
+    kernel_reasons, specs = _kops.flash_route(q.shape, k.shape, rules)
+    reasons += kernel_reasons
+    return reasons, (None if reasons else specs)
+
+
+def _record_sdpa(q, k, reasons: Tuple[str, ...], sharded: bool) -> None:
+    """One routing-census event for an attention core: role ``sdpa``,
+    route ``flash`` or ``chunked`` with the ``reasons`` that kept it
+    chunked."""
+    routing.record(
+        "sdpa", "chunked" if reasons else "flash", jnp.dtype(q.dtype).name,
+        jnp.dtype(k.dtype).name, reasons=reasons, sharded=sharded)
+
+
 @graph_span("attn")
 def attention(
     params: Dict[str, jnp.ndarray],
@@ -207,7 +237,8 @@ def attention(
     """Self-attention sublayer.  Returns (out, updated_cache)."""
     b, sq, _ = x.shape
     hd = cfg.resolved_head_dim
-    if positions is None:
+    default_positions = positions is None
+    if default_positions:
         positions = jnp.arange(sq, dtype=jnp.int32)
 
     q = linear(x, params["wq"], recipe, cfg,
@@ -230,18 +261,17 @@ def attention(
     v = shard_hint(v, ("batch", "seq", "kv_heads", None))
 
     window = cfg.sliding_window
+    reasons, specs = _flash_route(q, k, get_sharding_context(),
+                                  cache=cache, window=window,
+                                  default_positions=default_positions)
+    _record_sdpa(q, k, reasons, specs is not None)
     new_cache = None
-    if cache is None:
-        if (cfg.attention_impl == "pallas" and not window
-                and q.shape[1] % 128 == 0):
-            # TPU flash kernel (interpret-mode on CPU); bwd runs through the
-            # chunked-jnp path (kernels.ops custom_vjp) — identical math.
-            from repro.kernels import flash_attention as _flash
-            out = _flash(q, k, v, causal=causal, chunk=cfg.attention_chunk)
-        else:
-            out = chunked_attention(
-                q, k, v, positions, positions, causal=causal, window=window,
-                chunk=cfg.attention_chunk, unroll=cfg.unroll_attention)
+    if not reasons:
+        out = _kops.flash_attention(q, k, v, causal=causal, specs=specs)
+    elif cache is None:
+        out = chunked_attention(
+            q, k, v, positions, positions, causal=causal, window=window,
+            chunk=cfg.attention_chunk, unroll=cfg.unroll_attention)
     else:
         new_cache, k_all, v_all, k_pos = _update_cache(
             cache, k, v, cache_len, window, cfg.kv_cache_format)
@@ -288,6 +318,7 @@ def cross_attention(
     skv = k.shape[1]
     kpos = jnp.arange(skv, dtype=jnp.int32)
     qpos = jnp.zeros((sq,), jnp.int32)
+    _record_sdpa(q, k, ("cross",), False)
     out = chunked_attention(q, k, v, qpos, kpos, causal=False, window=0,
                             chunk=cfg.attention_chunk,
                             unroll=cfg.unroll_attention)

@@ -39,6 +39,15 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def chip_precision():
+    """The chip's matmul precision (the program sets none), in place of
+    the ``highest`` the test session sets, under which the splash kernels'
+    bf16 dots do not compile and attention keeps the chunked path."""
+    with jax.default_matmul_precision("default"):
+        yield
+
+
 def assert_trees_close(a, b, rtol=1e-5, atol=1e-5):
     import jax.numpy as jnp
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):

@@ -60,7 +60,9 @@ def test_quantize_per_row_matches_block_spec():
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("s,kvh", [(128, 4), (256, 2), (128, 1)])
+# kvh 4 of 4 heads is MHA, 2 GQA, 1 MQA; at 768 the tiles are 256, so the
+# causal mask skips whole tiles and masks the diagonal ones in part.
+@pytest.mark.parametrize("s,kvh", [(128, 4), (256, 2), (128, 1), (768, 2)])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_sweep(s, kvh, causal):
     b, h, d = 2, 4, 64
@@ -87,19 +89,23 @@ def test_flash_attention_bf16():
                                atol=3e-2)
 
 
-def test_flash_attention_grads_match_ref():
-    b, s, h, d = 1, 128, 2, 32
+@pytest.mark.parametrize("h,kvh,causal", [(2, 2, True), (4, 1, True),
+                                          (4, 2, False)])
+def test_flash_attention_grads_match_ref(h, kvh, causal):
+    """The dq and dkv kernels; with MQA and GQA, dk and dv sum over each
+    KV head's group of query heads."""
+    b, s, d = 1, 128, 32
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, h, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, s, kvh, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, s, kvh, d), jnp.float32)
 
     def f(fn):
         return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
                         argnums=(0, 1, 2))(q, k, v)
 
-    g = f(lambda q, k, v: flash_attention(q, k, v))
-    gr = f(lambda q, k, v: flash_attention_ref(q, k, v))
+    g = f(lambda q, k, v: flash_attention(q, k, v, causal=causal))
+    gr = f(lambda q, k, v: flash_attention_ref(q, k, v, causal=causal))
     for a, b_ in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-3, atol=1e-3)

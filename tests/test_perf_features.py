@@ -88,25 +88,38 @@ def test_all_fp4_sched_recipe_registered():
     assert s.switch_step == 90
 
 
-def test_pallas_attention_impl_matches_chunked():
-    """attention_impl='pallas' routes SDPA through the Pallas flash kernel
-    (interpret mode on CPU) and must match the chunked path."""
+def test_pallas_attention_impl_matches_chunked(monkeypatch, chip_precision):
+    """On a TPU, self-attention takes the splash flash kernels (here in
+    interpret mode, the platform test patched as the TPU compile tests do)
+    and the model must match its chunked path, both at the chip's matmul
+    precision."""
+    import functools
     import importlib
     import jax
-    import jax.numpy as jnp
+    from repro.core import routing
     from repro.core.recipe import RECIPES
     from repro.models import build_model
+    fm = importlib.import_module("repro.kernels.fp4_matmul")
+    ops = importlib.import_module("repro.kernels.ops")
     cfg = importlib.import_module("repro.configs.tiny").CONFIG.replace(
         dtype="float32")
     toks = jax.random.randint(jax.random.PRNGKey(0), (2, 128), 0,
                               cfg.vocab_size)
     batch = {"tokens": toks, "targets": toks}
-    outs = {}
-    for impl in ("chunked", "pallas"):
-        model = build_model(cfg.replace(attention_impl=impl))
-        params = model.init(jax.random.PRNGKey(1))
-        logits, _ = model.forward(params, batch, RECIPES["bf16"])
-        outs[impl] = logits
-    np.testing.assert_allclose(np.asarray(outs["pallas"]),
-                               np.asarray(outs["chunked"]),
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+
+    def forward():
+        with routing.capture() as log:
+            logits, _ = model.forward(params, batch, RECIPES["bf16"])
+        return logits, {ev.route for ev in log.cells() if ev.role == "sdpa"}
+
+    chunked, routes = forward()
+    assert routes == {"chunked"}
+    monkeypatch.setattr(fm, "interpret_mode", lambda: False)
+    monkeypatch.setattr(ops, "flash_attention", functools.partial(
+        ops.flash_attention, interpret=True))
+    flash, routes = forward()
+    assert routes == {"flash"}
+    np.testing.assert_allclose(np.asarray(flash), np.asarray(chunked),
                                rtol=2e-4, atol=2e-4)

@@ -178,6 +178,35 @@ def _tcfg(**kw):
     return TrainConfig(**kw)
 
 
+def test_f32_kernel_operands_flag_any_kernel_but_splash():
+    """A kernel with a float32 matrix operand is flagged wherever it
+    sits, also outside a matmul role; the splash attention kernels, whose
+    backward reads the float32 softmax statistics, are not."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.ops import flash_attention
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    x = jnp.ones((8, 128), jnp.float32)
+    toy = jax.make_jaxpr(lambda x: pl.pallas_call(
+        copy, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=True)(x))(x)
+    assert qlint.graph_census(toy)["f32_kernel_operands"] == [
+        "qrole_?: float32 operand (8, 128)"]
+
+    q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
+    splash = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q)
+    census = qlint.graph_census(splash)
+    assert census["pallas_calls"] == {"-": 3}
+    assert census["f32_kernel_operands"] == []
+
+
 def test_train_graph_audit_clean_and_covers_all_cells():
     cfg = get_config("tiny").replace(scan_layers=False,
                                     linear_impl="pallas")
@@ -252,7 +281,9 @@ def test_qdq_impl_routes_and_markers():
     report = qlint.audit_train_graph(cfg, _tcfg(), label="qdq",
                                      compile_hlo=False)
     assert report.violations() == []
-    quantized = [c for c in report.cells if c["cls"] in ("attn", "ffn")]
+    # the attention core (role sdpa) is no matmul role of the plan
+    quantized = [c for c in report.cells if c["cls"] in ("attn", "ffn")
+                 and c["role"] != "sdpa"]
     assert quantized and all(c["route"] == "qdq" for c in quantized)
     # QDQ path stages qdq_* markers under the qrole scopes
     assert report.summary["qdq_markers"]

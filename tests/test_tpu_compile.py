@@ -8,12 +8,18 @@ asserts that the compiled program still holds the Mosaic kernel
 the compiler refuses unaligned slices, scoped-VMEM overruns and
 operations Mosaic has no lowering for.
 
+The attention core is compiled too: on a TPU, self-attention runs JAX's
+splash flash kernels forward and backward (``kernels.ops.flash_attention``),
+so the step holds them and no float32 (..., S, S) score block.
+
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker that runs
 this file loads the TPU library.
 """
+import functools
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +27,8 @@ import pytest
 
 from repro.configs.base import TrainConfig
 from repro.configs.gpt2_125m import CONFIG as GPT2_125M
+from repro.configs.gpt2_774m import CONFIG as GPT2_774M
+from repro.core import routing
 from repro.core.recipe import RECIPES, as_plan
 from repro.distributed.mesh import make_mesh
 from repro.distributed.sharding import default_rules
@@ -29,9 +37,10 @@ from repro.train.train_step import (make_optimizer, make_train_step,
                                     train_step_shardings)
 
 FM = importlib.import_module("repro.kernels.fp4_matmul")
-FA = importlib.import_module("repro.kernels.flash_attention")
+OPS = importlib.import_module("repro.kernels.ops")
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
+SPLASH = re.compile(r"%splash_[\w.]+ = .*" + re.escape(KERNEL))
 TOKENS, D, FF, QKV = 8192, 768, 3072, 3 * 768
 
 
@@ -134,13 +143,34 @@ def test_fused_qmm_compiles_for_v5e(on_chip, case):
     assert (KERNEL in compiled.as_text()) == expect_kernel
 
 
-def test_flash_attention_fwd_compiles_for_v5e(on_chip):
-    # (batch 16 x 12 heads, seq 1024, head_dim 64)
-    q = _bf16(16 * 12, 1024, 64)
-    compiled = jax.jit(lambda q, k, v: FA.flash_attention_fwd(
-        q, k, v, bq=128, bk=128, interpret=False)).lower(
-            *on_chip((q, q, q))).compile()
-    assert KERNEL in compiled.as_text()
+def _scores_f32(text: str, seq: int) -> int:
+    """float32 arrays in the HLO whose two minor dims are (seq, seq)."""
+    return len(re.findall(rf"f32\[[\d,]*{seq},{seq}\]", text))
+
+
+def test_flash_attention_fwd_compiles_for_v5e(on_chip, chip_precision):
+    # (batch 16, seq 1024, 12 heads of 64): forward, dq and dkv kernels
+    q = _bf16(16, 1024, 12, 64)
+
+    def loss(q, k, v):
+        o = OPS.flash_attention(q, k, v, interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *on_chip((q, q, q))).compile()
+    text = compiled.as_text()
+    names = {m.split(" = ")[0].rsplit(".", 1)[0]
+             for m in SPLASH.findall(text)}
+    assert names == {"%splash_mha_fwd_residuals",
+                     "%splash_mha_dq_no_residuals",
+                     "%splash_mha_dkv_no_residuals"}
+    assert _scores_f32(text, 1024) == 0
+
+
+def _sdpa_routes(log):
+    """The (route, reasons) of the attention cores in a census."""
+    return {(ev.route, ev.reasons) for ev in log.cells()
+            if ev.role == "sdpa"}
 
 
 def _step_args(model, tcfg):
@@ -158,7 +188,9 @@ def _step_args(model, tcfg):
 def test_paper_fp4_train_step_compiles_for_v5e(on_chip, monkeypatch):
     """One whole fused-kernel paper_fp4 train step at GPT-2-125M widths,
     two layers deep; it must fit one 16 GB chip, and its Mosaic calls are
-    the one-device path's: 36 at these widths."""
+    the one-device path's: 36 at these widths.  At the ``highest`` matmul
+    precision the test session sets, the splash kernels' bf16 dots would
+    not compile, so attention keeps the chunked path and says why."""
     # Kernels decide interpret mode from the backend, which is the CPU
     # here: steer them to the chip's compiler.
     monkeypatch.setattr(FM, "interpret_mode", lambda: False)
@@ -169,7 +201,9 @@ def test_paper_fp4_train_step_compiles_for_v5e(on_chip, monkeypatch):
     step = make_train_step(model, tcfg,
                            as_plan(RECIPES["paper_fp4"], cfg.n_layers),
                            donate=False)
-    compiled = step.lower(*on_chip(_step_args(model, tcfg))).compile()
+    with routing.capture() as log:
+        compiled = step.lower(*on_chip(_step_args(model, tcfg))).compile()
+    assert _sdpa_routes(log) == {("chunked", ("precision",))}
     assert compiled.as_text().count(KERNEL) == 36
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -197,8 +231,98 @@ def test_paper_fp4_fsdp_step_compiles_for_v5e_2x2(topo, monkeypatch):
     step = make_train_step(model, tcfg,
                            as_plan(RECIPES["paper_fp4"], cfg.n_layers),
                            donate=False, rules=rules)
-    compiled = step.lower(*args).compile()
+    with routing.capture() as log:
+        compiled = step.lower(*args).compile()
+    assert _sdpa_routes(log) == {("chunked", ("precision",))}
     assert KERNEL in compiled.as_text()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
+
+
+def _gpt2_774m_layer(tcfg_kw):
+    cfg = GPT2_774M.replace(n_layers=1, linear_impl="pallas")
+    model = build_model(cfg)
+    tcfg = TrainConfig(recipe="paper_fp4", total_steps=20, seq_len=1024,
+                       **tcfg_kw)
+    return cfg, model, tcfg
+
+
+def test_gpt2_774m_layer_step_runs_flash_for_v5e(on_chip, monkeypatch,
+                                                 chip_precision):
+    """One GPT-2-774M-width layer's paper_fp4 train step (8 x 1024, 20
+    heads of 64) on a described v5e: attention runs the splash kernels,
+    and no float32 (..., 1024, 1024) score block is left in the step."""
+    monkeypatch.setattr(FM, "interpret_mode", lambda: False)
+    cfg, model, tcfg = _gpt2_774m_layer(dict(global_batch=8))
+    step = make_train_step(model, tcfg,
+                           as_plan(RECIPES["paper_fp4"], cfg.n_layers),
+                           donate=False)
+    with routing.capture() as log:
+        lowered = step.lower(*on_chip(_step_args(model, tcfg)))
+    assert _sdpa_routes(log) == {("flash", ())}
+    text = lowered.compile().as_text()
+    calls = SPLASH.findall(text)
+    assert len(calls) == 4
+    assert all("bf16[8,20,1024,64]" in c for c in calls)
+    assert _scores_f32(text, 1024) == 0
+
+
+def test_gpt2_774m_layer_fsdp_step_runs_flash_for_v5e_2x2(topo, monkeypatch,
+                                                          chip_precision):
+    """The same layer under the FSDP rules on a described v5e:2x2, mesh
+    (4, 1), 8 rows a chip: GSPMD cannot partition a Mosaic call, so the
+    splash kernels run inside ``shard_map``, each chip on its own rows."""
+    monkeypatch.setattr(FM, "interpret_mode", lambda: False)
+    cfg, model, tcfg = _gpt2_774m_layer(dict(global_batch=32,
+                                             mesh_shape=(4, 1), fsdp=True))
+    rules = default_rules(make_mesh((4, 1), ("data", "model"),
+                                    devices=topo.devices), cfg, fsdp=True)
+    shardings = list(train_step_shardings(model, tcfg, rules)[0])
+    shardings[3] = {"tokens": shardings[3], "targets": shardings[3]}
+    args = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), _step_args(model, tcfg),
+        tuple(shardings))
+    step = make_train_step(model, tcfg,
+                           as_plan(RECIPES["paper_fp4"], cfg.n_layers),
+                           donate=False, rules=rules)
+    with routing.capture() as log:
+        lowered = step.lower(*args)
+    assert _sdpa_routes(log) == {("flash", ())}
+    assert all(ev.sharded for ev in log.cells() if ev.role == "sdpa")
+    text = lowered.compile().as_text()
+    calls = SPLASH.findall(text)
+    assert len(calls) == 4
+    assert all("bf16[8,20,1024,64]" in c for c in calls)
+    assert _scores_f32(text, 1024) == 0
+
+
+def test_fp8_compressed_dp_step_keeps_chunked_attention_v5e_2x2(
+        topo, monkeypatch, chip_precision):
+    """The fp8-compressed data-parallel step computes each data shard's
+    gradients in a ``vmap`` whose rules leave the batch unmapped: inside
+    ``shard_map`` every chip would run every shard's attention, so the
+    core keeps the chunked path, which GSPMD partitions, and says why."""
+    from repro.optim.compression import init_compression_state
+    monkeypatch.setattr(FM, "interpret_mode", lambda: False)
+    cfg = GPT2_125M.replace(n_layers=1, linear_impl="pallas")
+    model = build_model(cfg)
+    tcfg = TrainConfig(recipe="paper_fp4", total_steps=20, global_batch=32,
+                       seq_len=1024, mesh_shape=(4, 1), fsdp=False,
+                       grad_compression="fp8")
+    rules = default_rules(make_mesh((4, 1), ("data", "model"),
+                                    devices=topo.devices), cfg, fsdp=False)
+    shardings = list(train_step_shardings(model, tcfg, rules)[0])
+    shardings[3] = {"tokens": shardings[3], "targets": shardings[3]}
+    args = list(_step_args(model, tcfg))
+    args[2] = jax.eval_shape(functools.partial(
+        init_compression_state, dp_size=rules.dp_size), args[0])
+    args = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), tuple(args), tuple(shardings))
+    step = make_train_step(model, tcfg,
+                           as_plan(RECIPES["paper_fp4"], cfg.n_layers),
+                           donate=False, rules=rules)
+    with routing.capture() as log:
+        text = step.lower(*args).compile().as_text()
+    assert _sdpa_routes(log) == {("chunked", ("mesh_axis_unused",))}
+    assert not SPLASH.findall(text)
